@@ -112,7 +112,6 @@ def compressed_psum_int8(x: jax.Array, axis_name: str) -> jax.Array:
 
 def make_compressed_allreduce(mesh, axis_name: str = "data"):
     """jit-able f(x) -> mean over ``axis_name`` with int8 wire format."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis_name]
@@ -120,7 +119,7 @@ def make_compressed_allreduce(mesh, axis_name: str = "data"):
     @jax.jit
     def allreduce_mean(x):
         """x: (n_workers, ...) per-worker gradients -> replicated mean."""
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda v: compressed_psum_int8(v[0], axis_name) / n,
             mesh=mesh, in_specs=P(axis_name), out_specs=P(),
         )

@@ -188,19 +188,9 @@ _LOGICAL_AXES = {
 
 
 def _active_mesh():
-    """The legacy `with mesh:` context mesh, or None (CPU single-device)."""
-    import warnings
-
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or mesh.empty:
-        return None
-    return mesh
+    """The mesh set by ``jax.set_mesh``, or None (CPU single-device)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _resolve(mesh, dim_size, name):

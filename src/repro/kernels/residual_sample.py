@@ -21,8 +21,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(u_ref, p_ref, q_ref, out_ref, z_scr, cum_scr, tok_scr, best_scr,
-            *, bv: int, n_v: int):
+_LANES = 128
+
+
+def tile_first_crossing(r, prev, target):
+    """First column of the (1, bv) non-negative tile ``r`` at which the
+    running sum ``prev + cumsum(r)`` exceeds ``target``, or ``bv`` if none.
+
+    The chip's kernel language has no cumsum, so each 128-lane chunk takes
+    its inclusive prefix sum as a matmul with an upper-triangular ones
+    matrix (at full float32 precision), carried across chunks by the
+    chunk's last (largest) value.  Returns ``(first, running sum)``.
+    """
+    bv = r.shape[1]
+    w = min(bv, _LANES)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
+    tri = tri.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    first = jnp.int32(bv)
+    run = prev
+    for c in range(bv // w):
+        chunk = r[:, c * w:(c + 1) * w]
+        cum = run + jax.lax.dot_general(
+            chunk, tri, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        first = jnp.minimum(first, jnp.min(
+            jnp.where(cum > target, lane + c * w, bv)))
+        run = jnp.max(cum)
+    return first, run
+
+
+def _kernel(u_ref, p_ref, q_ref, out_ref, st_scr, *, bv: int, n_v: int):
+    ni = pl.program_id(0)
     phase = pl.program_id(1)
     vi = pl.program_id(2)
 
@@ -31,48 +63,43 @@ def _kernel(u_ref, p_ref, q_ref, out_ref, z_scr, cum_scr, tok_scr, best_scr,
     r = jnp.maximum(p - q, 0.0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1) + vi * bv
 
+    # st_scr (SMEM): 0 Z, 1 running cumsum, 2 picked token, 3 best p,
+    # 4 argmax(p) for the degenerate fallback
     @pl.when((phase == 0) & (vi == 0))
     def _init():
-        z_scr[...] = jnp.zeros_like(z_scr)
-        cum_scr[...] = jnp.zeros_like(cum_scr)
-        tok_scr[...] = jnp.full_like(tok_scr, -1)
-        best_scr[...] = jnp.full_like(best_scr, -1e30)
+        st_scr[0] = 0.0
+        st_scr[1] = 0.0
+        st_scr[2] = -1.0
+        st_scr[3] = -1e30
+        st_scr[4] = -1.0
 
     @pl.when(phase == 0)
     def _accumulate():
-        z_scr[0, 0] = z_scr[0, 0] + jnp.sum(r)
-        # track argmax(p) for the degenerate fallback
+        st_scr[0] = st_scr[0] + jnp.sum(r)
         m_tile = jnp.max(p)
         arg_tile = jnp.max(jnp.where(p == m_tile, cols, -1))
 
-        @pl.when(m_tile > best_scr[0, 0])
+        @pl.when(m_tile > st_scr[3])
         def _upd():
-            best_scr[0, 0] = m_tile
-            best_scr[0, 1] = arg_tile.astype(jnp.float32)
+            st_scr[3] = m_tile
+            st_scr[4] = arg_tile.astype(jnp.float32)
 
     @pl.when(phase == 1)
     def _pick():
-        target = u_ref[0, 0] * z_scr[0, 0]
-        prev = cum_scr[0, 0]
-        tile_cum = prev + jnp.cumsum(r[0])        # (bv,)
-        crossed = tile_cum > target
-        # first crossing column within this tile (or bv if none)
-        idx_in_tile = jnp.argmax(crossed)
-        has = jnp.any(crossed)
+        first, run = tile_first_crossing(r, st_scr[1], u_ref[ni] * st_scr[0])
 
-        @pl.when(has & (tok_scr[0, 0] < 0))
+        @pl.when((first < bv) & (st_scr[2] < 0))
         def _record():
-            tok_scr[0, 0] = (vi * bv + idx_in_tile).astype(jnp.float32)
+            st_scr[2] = (vi * bv + first).astype(jnp.float32)
 
-        cum_scr[0, 0] = prev + jnp.sum(r)
+        st_scr[1] = run
 
         @pl.when(vi == n_v - 1)
         def _finish():
-            degenerate = z_scr[0, 0] <= 0.0
-            fallback = best_scr[0, 1]
-            picked = tok_scr[0, 0]
-            picked = jnp.where(picked < 0, fallback, picked)
-            out_ref[0, 0] = jnp.where(degenerate, fallback, picked).astype(jnp.int32)
+            fallback = st_scr[4]
+            picked = jnp.where(st_scr[2] < 0, fallback, st_scr[2])
+            tok = jnp.where(st_scr[0] <= 0.0, fallback, picked)
+            out_ref[...] = jnp.full(out_ref.shape, tok.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("bv", "interpret"))
@@ -84,26 +111,22 @@ def residual_sample_pallas(p: jax.Array, q: jax.Array, u: jax.Array,
     if v_pad:
         p = jnp.pad(p, ((0, 0), (0, v_pad)))
         q = jnp.pad(q, ((0, 0), (0, v_pad)))
-    Vp = p.shape[1]
-    n_v = Vp // bv
-    u2d = u.astype(jnp.float32)[:, None]
+    n_v = p.shape[1] // bv
+    # rows carry a unit middle dim, squeezed out of the block: the chip's
+    # tiling then sees (1, bv) / (1, 1) blocks that span that whole dim
+    tile = lambda ni, ph, vi: (ni, 0, vi)
 
     out = pl.pallas_call(
         functools.partial(_kernel, bv=bv, n_v=n_v),
         grid=(N, 2, n_v),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda ni, ph, vi: (ni, 0)),
-            pl.BlockSpec((1, bv), lambda ni, ph, vi: (ni, vi)),
-            pl.BlockSpec((1, bv), lambda ni, ph, vi: (ni, vi)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),        # u
+            pl.BlockSpec((None, 1, bv), tile),
+            pl.BlockSpec((None, 1, bv), tile),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda ni, ph, vi: (ni, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),   # Z
-            pltpu.VMEM((1, 128), jnp.float32),   # running cumsum
-            pltpu.VMEM((1, 128), jnp.float32),   # picked token
-            pltpu.VMEM((1, 128), jnp.float32),   # (best p, argmax) fallback
-        ],
+        out_specs=pl.BlockSpec((None, 1, 1), lambda ni, ph, vi: (ni, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1, 1), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((8,), jnp.float32)],
         interpret=interpret,
-    )(u2d, p, q)
-    return out[:, 0]
+    )(u.astype(jnp.float32), p[:, None], q[:, None])
+    return out[:, 0, 0]

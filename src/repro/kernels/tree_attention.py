@@ -34,7 +34,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG = -1e30
+from .paged_attention import (
+    finish_softmax,
+    head_major,
+    init_softmax,
+    softmax_scratch,
+    token_major,
+    update_softmax,
+)
 _DOT_1_1 = (((1,), (1,)), ((), ()))
 _DOT_1_0 = (((1,), (0,)), ((), ()))
 
@@ -43,80 +50,47 @@ def _win_allow(mask_f, base, tile0, tile_w, T, gsize):
     """(T * gsize, tile_w) float: 1.0 where query row r (= t * gsize + g) may
     attend the window node living at slot ``tile0 + column``.
 
-    ``mask_f`` is the (T, T) ancestor matrix as float32; slot -> window-node
-    membership is resolved by a one-hot matmul so no gather is needed:
-    column c holds window node ``tile0 + c - base`` when that lands in
-    [0, T).
+    ``mask_f`` is the (T, T) ancestor matrix as float32.  Row -> node and
+    slot -> node membership are both resolved by one-hot matmuls, so the
+    kernel needs no gather and no reshape: column c holds window node
+    ``tile0 + c - base`` when that lands in [0, T).
     """
+    R = T * gsize
+    rrow = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0) // gsize
+    rcol = jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+    rowsel = (rrow == rcol).astype(jnp.float32)  # (R, T)
     kcol = jax.lax.broadcasted_iota(jnp.int32, (tile_w, T), 0) + tile0
     tcol = jax.lax.broadcasted_iota(jnp.int32, (tile_w, T), 1)
     onehot = (kcol - base == tcol).astype(jnp.float32)  # (tile_w, T)
-    allow_t = jax.lax.dot_general(mask_f, onehot, _DOT_1_1, preferred_element_type=jnp.float32)
-    allow = jnp.broadcast_to(allow_t[:, None, :], (T, gsize, tile_w))
-    return allow.reshape(T * gsize, tile_w)
+    mask_rows = jax.lax.dot_general(rowsel, mask_f, _DOT_1_0, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(mask_rows, onehot, _DOT_1_1, preferred_element_type=jnp.float32)
 
 
-def _flash_update(s, v, m_scr, l_scr, acc_scr):
-    """One online-softmax accumulation step over masked scores ``s``."""
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_scr[:, :1] = m_new
-    pv = jax.lax.dot_general(p, v, _DOT_1_0, preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * corr + pv
+def _update(q_ref, k_ref, v_ref, mask_ref, m_scr, l_scr, acc_scr, *, base, tile0, tile_w, T, gsize, n_kv, scale):
+    """Attend one K/V tile: committed slots plus in-window ancestors."""
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (T * gsize, tile_w), 1) + tile0
+    allow = _win_allow(mask_ref[0].astype(jnp.float32), base, tile0, tile_w, T, gsize)
+    update_softmax(q_ref, k_ref, v_ref, (kpos < base) | (allow > 0.5), m_scr, l_scr, acc_scr, n_kv=n_kv, scale=scale)
 
 
-def _kernel(
-    len_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    mask_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    bs,
-    n_s,
-    T,
-    gsize,
-    scale,
-):
+def _kernel(len_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *, bs, n_s, T, gsize, n_kv, scale):
     b = pl.program_id(0)
-    si = pl.program_id(2)
-    R = T * gsize
+    si = pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
     base = len_ref[b]
-    # tiles wholly past the window horizon contribute nothing
-    tile_live = si * bs < base + T
 
-    @pl.when(tile_live)
-    def _update():
-        D = q_ref.shape[-1]
-        q = q_ref[0, :, 0, :, :].astype(jnp.float32).reshape(R, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, _DOT_1_1, preferred_element_type=jnp.float32) * scale
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1) + si * bs
-        committed = kpos < base
-        allow = _win_allow(mask_ref[0].astype(jnp.float32), base, si * bs, bs, T, gsize)
-        s = jnp.where(committed | (allow > 0.5), s, _NEG)
-        _flash_update(s, v, m_scr, l_scr, acc_scr)
+    # tiles wholly past the window horizon contribute nothing
+    @pl.when(si * bs < base + T)
+    def _tile():
+        _update(q_ref, k_ref, v_ref, mask_ref, m_scr, l_scr, acc_scr, base=base, tile0=si * bs, tile_w=bs, T=T, gsize=gsize, n_kv=n_kv, scale=scale)
 
     @pl.when(si == n_s - 1)
     def _finish():
-        D = q_ref.shape[-1]
-        out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, :, 0, :, :] = out.reshape(T, gsize, D).astype(o_ref.dtype)
+        finish_softmax(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -125,95 +99,58 @@ def tree_attention_pallas(q, k_cache, v_cache, lengths, win_mask, bs=128, interp
     at slots [lengths_b, lengths_b + T); lengths: (B,); win_mask: (B, T, T)
     bool ancestor-or-self matrix.  Returns (B, T, H, D).
 
-    Grid: (B, KV, S-tiles); all T window rows x G = H/KV query heads of one
-    kv head share the (T*G, D) q tile so each KV tile is streamed once per
-    kv head (the GQA + tree-window bandwidth win).
+    Grid: (B, S-tiles); a K/V block is one S tile with every kv head, so
+    each tile is streamed once per row.  All T window rows x G = H/KV query
+    heads of one kv head share a (T*G, D) q tile (the GQA + tree-window
+    bandwidth win).
     """
     B, T, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
-    G = H // KV
-    scale = 1.0 / np.sqrt(D)
+    R = T * (H // KV)
     bs = min(bs, S)
     s_pad = (-S) % bs
     if s_pad:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
     n_s = k_cache.shape[1] // bs
-    qg = q.reshape(B, T, KV, G, D)
 
-    kernel = functools.partial(_kernel, bs=bs, n_s=n_s, T=T, gsize=G, scale=scale)
+    kernel = functools.partial(_kernel, bs=bs, n_s=n_s, T=T, gsize=H // KV, n_kv=KV, scale=1.0 / np.sqrt(D))
     out = pl.pallas_call(
         kernel,
-        grid=(B, KV, n_s),
+        grid=(B, n_s),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths
-            pl.BlockSpec((1, T, 1, G, D), lambda b, h, si: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, si: (b, si, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, si: (b, si, h, 0)),
-            pl.BlockSpec((1, T, T), lambda b, h, si: (b, 0, 0)),
+            pl.BlockSpec((1, KV, R, D), lambda b, si: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, KV, D), lambda b, si: (b, si, 0, 0)),
+            pl.BlockSpec((1, bs, KV, D), lambda b, si: (b, si, 0, 0)),
+            pl.BlockSpec((1, T, T), lambda b, si: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, 1, G, D), lambda b, h, si: (b, 0, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, KV, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((T * G, 128), jnp.float32),
-            pltpu.VMEM((T * G, 128), jnp.float32),
-            pltpu.VMEM((T * G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, R, D), lambda b, si: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
+        scratch_shapes=softmax_scratch(KV, R, D),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache, win_mask.astype(jnp.int32))
-    return out.reshape(B, T, H, D)
+    )(lengths.astype(jnp.int32), head_major(q, KV), k_cache, v_cache, win_mask.astype(jnp.int32))
+    return token_major(out, T)
 
 
-def _paged_kernel(
-    pt_ref,
-    len_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    mask_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    ps,
-    n_slots,
-    T,
-    gsize,
-    scale,
-):
+def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *, ps, n_slots, T, gsize, n_kv, scale):
     b = pl.program_id(0)
-    si = pl.program_id(2)
-    R = T * gsize
+    si = pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
     base = len_ref[b]
-    # unmapped slots or slots wholly past the window horizon skip the DMA
-    page_live = (pt_ref[b, si] >= 0) & (si * ps < base + T)
 
-    @pl.when(page_live)
-    def _update():
-        D = q_ref.shape[-1]
-        q = q_ref[0, :, 0, :, :].astype(jnp.float32).reshape(R, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, _DOT_1_1, preferred_element_type=jnp.float32) * scale
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1) + si * ps
-        committed = kpos < base
-        allow = _win_allow(mask_ref[0].astype(jnp.float32), base, si * ps, ps, T, gsize)
-        s = jnp.where(committed | (allow > 0.5), s, _NEG)
-        _flash_update(s, v, m_scr, l_scr, acc_scr)
+    # unmapped slots or slots wholly past the window horizon skip the DMA
+    @pl.when((pt_ref[b, si] >= 0) & (si * ps < base + T))
+    def _tile():
+        _update(q_ref, k_ref, v_ref, mask_ref, m_scr, l_scr, acc_scr, base=base, tile0=si * ps, tile_w=ps, T=T, gsize=gsize, n_kv=n_kv, scale=scale)
 
     @pl.when(si == n_slots - 1)
     def _finish():
-        D = q_ref.shape[-1]
-        out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, :, 0, :, :] = out.reshape(T, gsize, D).astype(o_ref.dtype)
+        finish_softmax(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -226,44 +163,40 @@ def paged_tree_attention_pallas(q, k_pool, v_pool, page_table, lengths, win_mask
     slot -> physical page before each DMA, exactly like ``paged_attention``.
     """
     B, T, H, D = q.shape
-    ps = k_pool.shape[1]
-    KV = k_pool.shape[2]
+    ps, KV = k_pool.shape[1], k_pool.shape[2]
     n_slots = page_table.shape[1]
-    G = H // KV
-    scale = 1.0 / np.sqrt(D)
-    qg = q.reshape(B, T, KV, G, D)
+    R = T * (H // KV)
 
-    def kv_map(b, h, si, pt, ln):
-        return (jnp.maximum(pt[b, si], 0), 0, h, 0)
+    def q_map(b, si, pt, ln):
+        return (b, 0, 0, 0)
+
+    def kv_map(b, si, pt, ln):
+        return (jnp.maximum(pt[b, si], 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, n_slots),
+        grid=(B, n_slots),
         in_specs=[
-            pl.BlockSpec((1, T, 1, G, D), lambda b, h, si, pt, ln: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
-            pl.BlockSpec((1, T, T), lambda b, h, si, pt, ln: (b, 0, 0)),
+            pl.BlockSpec((1, KV, R, D), q_map),
+            pl.BlockSpec((1, ps, KV, D), kv_map),
+            pl.BlockSpec((1, ps, KV, D), kv_map),
+            pl.BlockSpec((1, T, T), lambda b, si, pt, ln: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, 1, G, D), lambda b, h, si, pt, ln: (b, 0, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((T * G, 128), jnp.float32),
-            pltpu.VMEM((T * G, 128), jnp.float32),
-            pltpu.VMEM((T * G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, R, D), q_map),
+        scratch_shapes=softmax_scratch(KV, R, D),
     )
-    kernel = functools.partial(_paged_kernel, ps=ps, n_slots=n_slots, T=T, gsize=G, scale=scale)
+    kernel = functools.partial(_paged_kernel, ps=ps, n_slots=n_slots, T=T, gsize=H // KV, n_kv=KV, scale=1.0 / np.sqrt(D))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, KV, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),
         lengths.astype(jnp.int32),
-        qg,
+        head_major(q, KV),
         k_pool,
         v_pool,
         win_mask.astype(jnp.int32),
     )
-    return out.reshape(B, T, H, D)
+    return token_major(out, T)

@@ -91,7 +91,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                      in_shardings=(p_sh, o_sh, b_sh),
                      out_shardings=(p_sh, o_sh, None),
                      donate_argnums=(0, 1))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_shape, opt_shape, specs)
     elif shape.kind == "prefill":
         max_len = shape.seq_len + prefix_len(cfg)
@@ -116,7 +116,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                      in_shardings=(p_sh, b_sh["tokens"], c_sh) +
                      ((b_sh["prefix_embeds"],) if "prefix_embeds" in specs else ()),
                      donate_argnums=(2,))
-        with mesh:
+        with jax.set_mesh(mesh):
             args = [params_shape, specs["tokens"], cache_shape]
             if "prefix_embeds" in specs:
                 args.append(specs["prefix_embeds"])
@@ -137,7 +137,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         fn = jax.jit(serve_step,
                      in_shardings=(p_sh, b_sh["tokens"], c_sh, b_sh["pos"]),
                      donate_argnums=(2,))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_shape, specs["tokens"], cache_shape,
                                specs["pos"])
 

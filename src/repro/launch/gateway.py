@@ -3,6 +3,9 @@
   PYTHONPATH=src python -m repro.launch.gateway --port 8011         # synthetic
   PYTHONPATH=src python -m repro.launch.gateway --backend engine \
       --arch qwen2.5-3b --smoke-arch --scheme hete
+  PYTHONPATH=src python -m repro.launch.gateway --backend engine \
+      --arch qwen2.5-3b --draft-arch qwen3.5-0.8b --max-len 1024 \
+      --compile jit+donate                       # full width, bf16, TPU
 
 Stands up a ``MultiSpinCell`` and serves it live over HTTP/1.1 + SSE
 (``POST /v1/generate`` streams committed tokens per round; ``GET /metrics``
@@ -45,20 +48,32 @@ def build_cell(args):
         return MultiSpinCell(cfg)
 
     import jax
+    import jax.numpy as jnp
 
     from repro.api import EngineBackend, SpecEngine
     from repro.configs import get_config
 
     tcfg = get_config(args.arch)
+    if args.draft_arch:
+        dcfg = get_config(args.draft_arch)
+    else:
+        dcfg = tcfg.smoke().replace(num_layers=1, d_model=64, num_heads=2,
+                                    num_kv_heads=1, head_dim=32, d_ff=128,
+                                    vocab_size=tcfg.vocab_size, name="draft")
     if args.smoke_arch:
-        tcfg = tcfg.smoke()
-    dcfg = tcfg.smoke().replace(num_layers=1, d_model=64, num_heads=2,
-                                num_kv_heads=1, head_dim=32, d_ff=128,
-                                vocab_size=tcfg.vocab_size, name="draft")
+        tcfg, cache_dtype = tcfg.smoke(), jnp.float32
+        if args.draft_arch:
+            dcfg = dcfg.smoke()
+        dcfg = dcfg.replace(vocab_size=tcfg.vocab_size)
+    else:
+        # full width serves bf16 weights and a bf16 KV cache
+        tcfg, dcfg = (c.replace(param_dtype="bfloat16",
+                                compute_dtype="bfloat16")
+                      for c in (tcfg, dcfg))
+        cache_dtype = jnp.bfloat16
     engine = SpecEngine(tcfg, dcfg, max_len=args.max_len, cache_kind="paged",
                         num_pages=args.max_batch * 2 * (args.max_len // 16),
-                        compile_mode=args.compile,
-                        compile_cache=args.compile_cache)
+                        compile_mode=args.compile, cache_dtype=cache_dtype)
     engine.init_params(jax.random.PRNGKey(args.seed))
     prompts = jax.random.randint(jax.random.PRNGKey(args.seed + 1),
                                  (args.max_batch, 8), 0, tcfg.vocab_size)
@@ -72,8 +87,7 @@ def build_cell(args):
         state, info = engine.warmup(
             state, sorted({(args.max_batch, L) for L in lengths}))
         print(f"warmup: traced {len(info)} bucket(s) in "
-              f"{sum(info.values()):.1f}s "
-              f"(compile cache: {args.compile_cache or 'off'})")
+              f"{sum(info.values()):.1f}s")
     backend = EngineBackend(engine, state, keep_finished_tokens=True)
     cfg = CellConfig(scheme=args.scheme, scheme_params=scheme_params,
                      schedule=args.schedule, max_batch=args.max_batch,
@@ -131,7 +145,7 @@ async def _smoke(args):
         raise SystemExit(f"gateway smoke FAILED: {report['errors']}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         formatter_class=argparse.RawDescriptionHelpFormatter,
         epilog=scheme_help_text())
@@ -142,16 +156,18 @@ def main():
                     choices=("synthetic", "engine"))
     ap.add_argument("--arch", default="qwen2.5-3b",
                     help="target architecture (engine backend)")
+    ap.add_argument("--draft-arch", default=None,
+                    help="draft architecture by registry name (engine "
+                         "backend; default: a one-layer draft derived from "
+                         "the target's smoke config)")
     ap.add_argument("--smoke-arch", action="store_true",
-                    help="shrink the engine arch to smoke scale")
+                    help="shrink the engine archs to smoke scale (float32); "
+                         "without it the engine serves bfloat16")
     ap.add_argument("--max-len", type=int, default=256,
                     help="engine stream length ceiling")
     ap.add_argument("--compile", default="eager",
                     choices=("eager", "jit", "jit+donate"),
                     help="engine round-path compile mode (engine backend)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(also: REPRO_COMPILE_CACHE)")
     ap.add_argument("--warmup-lengths", default="", metavar="L1,L2,...",
                     help="draft depths to pre-trace at startup when "
                          "--compile != eager (default: L-max only)")
@@ -174,7 +190,14 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="no server: in-process loadgen burst, print report")
     ap.add_argument("--smoke-requests", type=int, default=8)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    if args.backend == "engine":
+        from repro.serving.compiled import setup_compilation_cache
+        setup_compilation_cache()
     try:
         asyncio.run(_smoke(args) if args.smoke else _serve(args))
     except KeyboardInterrupt:

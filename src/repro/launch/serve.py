@@ -54,6 +54,9 @@ def main():
     import jax
     import numpy as np
 
+    from repro.serving.compiled import setup_compilation_cache
+    setup_compilation_cache()
+
     from repro.api import (
         CellConfig,
         ChannelConfig,

@@ -25,13 +25,20 @@ Params = Any  # nested dict pytree
 # Initializers
 # ---------------------------------------------------------------------------
 
+def std_normal(key, shape):
+    """Standard normal sample behind an optimization barrier: under ``jit``
+    XLA would otherwise fold the caller's scale into the sampler's own
+    constants, and jitted and eager inits would differ in the last bit."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape))
+
+
 def dense_init(key, in_dim: int, out_dim: int, dtype=jnp.float32, scale: float | None = None):
     scale = scale if scale is not None else 1.0 / np.sqrt(in_dim)
-    return (jax.random.normal(key, (in_dim, out_dim)) * scale).astype(dtype)
+    return (std_normal(key, (in_dim, out_dim)) * scale).astype(dtype)
 
 
 def embed_init(key, vocab: int, dim: int, dtype=jnp.float32):
-    return (jax.random.normal(key, (vocab, dim)) * 0.02).astype(dtype)
+    return (std_normal(key, (vocab, dim)) * 0.02).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -416,9 +423,9 @@ def init_moe(key, d_model: int, mcfg: MoEConfig, dtype=jnp.float32) -> Params:
     scale = 1.0 / np.sqrt(d_model)
     p = {
         "router": dense_init(keys[0], d_model, E, dtype=jnp.float32),
-        "w_gate": (jax.random.normal(keys[1], (E, d_model, F)) * scale).astype(dtype),
-        "w_up": (jax.random.normal(keys[2], (E, d_model, F)) * scale).astype(dtype),
-        "w_down": (jax.random.normal(keys[3], (E, F, d_model)) / np.sqrt(F)).astype(dtype),
+        "w_gate": (std_normal(keys[1], (E, d_model, F)) * scale).astype(dtype),
+        "w_up": (std_normal(keys[2], (E, d_model, F)) * scale).astype(dtype),
+        "w_down": (std_normal(keys[3], (E, F, d_model)) * (1.0 / np.sqrt(F))).astype(dtype),
     }
     if mcfg.num_shared_experts > 0:
         p["shared"] = init_mlp(keys[4], d_model,

@@ -118,16 +118,20 @@ class DecoderLM:
         if not cfg.tie_embeddings:
             params["unembed"] = dense_init(k_head, cfg.d_model, cfg.vocab_size, dtype)
 
+        # ``lax.map`` writes each layer straight into the stacked leaves, so
+        # no per-layer copy is ever live beside the stack
         if cfg.num_experts:
             if n_dense:
                 keys = jax.random.split(k_extra, n_dense)
-                params["dense_blocks"] = _stack([self._init_block(k, moe=False)
-                                                 for k in keys])
+                params["dense_blocks"] = jax.lax.map(
+                    lambda k: self._init_block(k, moe=False), keys)
             keys = jax.random.split(k_blocks, n_moe)
-            params["blocks"] = _stack([self._init_block(k, moe=True) for k in keys])
+            params["blocks"] = jax.lax.map(
+                lambda k: self._init_block(k, moe=True), keys)
         else:
             keys = jax.random.split(k_blocks, cfg.num_layers)
-            params["blocks"] = _stack([self._init_block(k, moe=False) for k in keys])
+            params["blocks"] = jax.lax.map(
+                lambda k: self._init_block(k, moe=False), keys)
         return params
 
     # ------------------------------------------------------------------
@@ -232,51 +236,31 @@ class DecoderLM:
         if cfg.remat:
             block_fn = jax.checkpoint(block_fn)
 
-        def scan_body(carry, xs):
-            x = carry
-            if use_cache:
-                p, kc, vc = xs
-                kv_in = (kc, vc)
-            else:
-                p = xs
-                kv_in = None
-            x, kv, aux = block_fn(p, x, kv_in)
-            return x, (kv[0], kv[1], aux)
-
         new_cache = dict(cache) if use_cache else None
         aux_total = jnp.zeros((), jnp.float32)
-
-        # Leading dense blocks (MoE stacks with first_k_dense > 0).
+        stacks = [("blocks", "k", "v", block_fn)]
         if "dense_blocks" in params:
-            def dense_body(carry, xs):
-                x = carry
-                if use_cache:
-                    p, kc, vc = xs
-                    kv_in = (kc, vc)
-                else:
-                    p = xs
-                    kv_in = None
-                x, kv, aux = self._block_apply(
+            # leading dense blocks (MoE stacks with first_k_dense > 0)
+            def dense_fn(p, x, kv_in):
+                return self._block_apply(
                     p, x, moe=False, positions=positions, mask=mask,
                     kv_cache=kv_in, offset=offset, page_table=page_table,
                     window_mask=window_mask, causal_window=causal_window)
-                return x, (kv[0], kv[1], aux)
+            stacks.insert(0, ("dense_blocks", "dense_k", "dense_v", dense_fn))
 
-            xs = ((params["dense_blocks"], cache["dense_k"], cache["dense_v"])
-                  if use_cache else params["dense_blocks"])
-            x, (dk, dv, aux) = jax.lax.scan(dense_body, x, xs,
-                                            unroll=cfg.scan_unroll)
-            aux_total += jnp.sum(aux)
+        for blocks, k_leaf, v_leaf, fn in stacks:
             if use_cache:
-                new_cache["dense_k"], new_cache["dense_v"] = dk, dv
-
-        xs = ((params["blocks"], cache["k"], cache["v"]) if use_cache
-              else params["blocks"])
-        x, (k_new, v_new, aux) = jax.lax.scan(scan_body, x, xs,
-                                              unroll=cfg.scan_unroll)
-        aux_total += jnp.sum(aux)
-        if use_cache:
-            new_cache["k"], new_cache["v"] = k_new, v_new
+                x, k_all, v_all, aux = _scan_with_cache(
+                    fn, x, params[blocks], cache[k_leaf], cache[v_leaf],
+                    cfg.scan_unroll)
+                new_cache[k_leaf], new_cache[v_leaf] = k_all, v_all
+            else:
+                def body(x, p, fn=fn):
+                    x, _, aux = fn(p, x, None)
+                    return x, aux
+                x, aux = jax.lax.scan(body, x, params[blocks],
+                                      unroll=cfg.scan_unroll)
+            aux_total += jnp.sum(aux)
         return x, new_cache, aux_total
 
     def _embed(self, params, tokens, prefix_embeds=None):
@@ -382,6 +366,29 @@ class DecoderLM:
 
     def num_params(self, params) -> int:
         return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+
+
+def _scan_with_cache(layer_fn, x, blocks, k_all, v_all, unroll):
+    """Scan ``layer_fn(p, x, (k, v)) -> (x, (k, v), aux)`` over stacked
+    layers with the stacked K/V caches (layers first) in the CARRY: each
+    layer reads its slice and writes it back in place.  Scanned over as
+    inputs instead, the caches would come back as a new stacked output, a
+    fresh copy of every pool per window pass."""
+
+    def body(carry, xs):
+        x, k_all, v_all = carry
+        p, i = xs
+        kv_in = (jax.lax.dynamic_index_in_dim(k_all, i, keepdims=False),
+                 jax.lax.dynamic_index_in_dim(v_all, i, keepdims=False))
+        x, (k, v), aux = layer_fn(p, x, kv_in)
+        k_all = jax.lax.dynamic_update_index_in_dim(k_all, k, i, 0)
+        v_all = jax.lax.dynamic_update_index_in_dim(v_all, v, i, 0)
+        return (x, k_all, v_all), aux
+
+    layers = jnp.arange(k_all.shape[0], dtype=jnp.int32)
+    (x, k_all, v_all), aux = jax.lax.scan(body, (x, k_all, v_all),
+                                          (blocks, layers), unroll=unroll)
+    return x, k_all, v_all, aux
 
 
 def _stack(trees: list[Params]) -> Params:

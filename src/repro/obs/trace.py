@@ -111,7 +111,10 @@ class Span:
         self.args.update(args)
 
     def attach(self, value):
-        self._sync = value
+        # kept only to block on at exit: a recorded span must not hold a
+        # device buffer (a KV pool, logits) for as long as the ring keeps it
+        if self.tracer.device_sync:
+            self._sync = value
 
     def __enter__(self):
         self.tracer._enter(self)

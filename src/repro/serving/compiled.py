@@ -10,6 +10,14 @@ closure-captured — so ``jax.jit`` can alias the donated buffers:
   * ``verify_step`` — donates the TARGET KV cache (argnum 1)
   * ``commit_step`` — donates pending / target_pos / draft_pos (0, 1, 2)
 
+and the token-tree round's (``multidraft``, J > 1) three steps:
+
+  * ``forest_step``      — J drafting runs; donates the DRAFT KV cache
+  * ``tree_verify_step`` — ancestor-masked target pass + tree accept test;
+                           donates the TARGET KV cache
+  * ``tree_commit_step`` — scatter-commit of the winning branch's K/V;
+                           donates both KV caches
+
 Donation invariants (docs/architecture.md "compilation & memory model"):
 
   * a donated buffer is DEAD after the call — the engine adopts the
@@ -31,40 +39,45 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from pathlib import Path
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.drafting import generate_drafts
-from repro.core.verification import verify_drafts
+from repro.core.drafting import generate_draft_forest, generate_drafts
+from repro.core.verification import verify_drafts, verify_tree
+from repro.models.layers import gather_kv_window, scatter_kv_window
 from repro.models.transformer import strip_view
 
 COMPILE_MODES = ("eager", "jit", "jit+donate")
 
 
-def setup_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Enable JAX's persistent compilation cache at ``cache_dir``.
+_REPO_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-    Falls back to the ``REPRO_COMPILE_CACHE`` env var when ``cache_dir`` is
-    None; returns the directory actually installed (or None when disabled).
-    Cold gateway starts recompile the full round path (~minutes at real
-    shapes); with the cache installed a restart at the same shapes loads
-    the compiled executables from disk instead.
+
+def setup_compilation_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set (JAX
+    reads the variable itself; nothing here overrides it), else the fixed
+    ``<repo root>/.jax_cache``: the path is part of the cache key, so it is
+    never built from a temp name, a pid or the time.  Without the variable
+    a CPU backend gets no cache, so tests and CPU runs write nothing into
+    the checkout.  Returns the directory in use, or None.
+
+    Cold gateway starts compile the full round path (minutes at real
+    shapes); with the cache a restart at the same shapes loads the
+    executables from disk, so every round-step executable is persisted.
     """
-    cache_dir = cache_dir or os.environ.get("REPRO_COMPILE_CACHE")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # default thresholds skip small/fast compiles; serving wants every
-    # round-step executable persisted so warm restarts pay zero compiles
+        if jax.default_backend() == "cpu":
+            return None
+        cache_dir = str(_REPO_CACHE)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (AttributeError, ValueError):  # older jax spells it differently
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
 
 
@@ -99,11 +112,46 @@ def commit_step(pending: jax.Array, target_pos: jax.Array,
     return pending, target_pos, draft_pos, emission
 
 
+def tree_scatter_commit(t_cache, d_cache, windows, paths, winner,
+                        accept_counts, frozen, target_pos, draft_pos):
+    """Land the accepted tree branch's K/V in its committed slots.
+
+    The ancestor-masked target pass already computed every node's K/V
+    conditioned on exactly its root-to-node path, so the winning branch's
+    rows move from their tree-window slots into slots [pos + 1, pos + n];
+    the draft side takes them from the winning run's window snapshot
+    (``DraftForest.windows``).  Caches are views (``"pages"`` kept when
+    present); returns the updated (t_cache, d_cache).
+    """
+    L = paths.shape[2]
+    path_w = jnp.take_along_axis(paths, winner[:, None, None],
+                                 axis=1)[:, 0]                  # (B, L)
+    keep = ((jnp.arange(L)[None, :] < accept_counts[:, None])
+            & (path_w >= 0) & ~frozen[:, None])
+    col = jnp.arange(L, dtype=jnp.int32)[None, :]
+    src_t = target_pos[:, None] + 1 + jnp.maximum(path_w, 0)
+    dst_t = target_pos[:, None] + 1 + col
+    dst_d = draft_pos[:, None] + 1 + col
+    t_cache, d_cache = dict(t_cache), dict(d_cache)
+    t_pt, d_pt = t_cache.get("pages"), d_cache.get("pages")
+    for leaf in windows:
+        vals = gather_kv_window(t_cache[leaf], src_t, page_table=t_pt)
+        t_cache[leaf] = scatter_kv_window(t_cache[leaf], vals, dst_t, keep,
+                                          page_table=t_pt)
+        win = jnp.take_along_axis(
+            windows[leaf], winner[None, :, None, None, None, None],
+            axis=2)[:, :, 0]                                   # (Ln,B,L,KV,D)
+        d_cache[leaf] = scatter_kv_window(d_cache[leaf], win, dst_d, keep,
+                                          page_table=d_pt)
+    return t_cache, d_cache
+
+
 @dataclasses.dataclass
 class RoundSteps:
-    """The three compiled (or eager) step callables for one engine.
+    """The compiled (or eager) step callables for one engine.
 
-    ``draft`` / ``verify`` are None in eager mode — the engine keeps its
+    ``draft`` / ``verify`` and the tree steps ``forest`` / ``tree_verify``
+    / ``tree_commit`` are None in eager mode — the engine keeps its
     op-by-op dispatch path; ``commit`` is always callable (the eager path
     shares the same device-side commit math, just unjitted).
     """
@@ -112,6 +160,9 @@ class RoundSteps:
     draft: Callable | None
     verify: Callable | None
     commit: Callable
+    forest: Callable | None = None
+    tree_verify: Callable | None = None
+    tree_commit: Callable | None = None
 
 
 def build_round_steps(target_model, draft_model, *, mode: str,
@@ -156,6 +207,37 @@ def build_round_steps(target_model, draft_model, *, mode: str,
         return commit_step(pending, target_pos, draft_pos, rows, skip,
                            output_tokens, accept_counts)
 
+    def forest_step(params, kv, pages, pending, dpos, key, *, L, J, vhat,
+                    keep_windows):
+        _record("forest", pending.shape[0], L)
+        cache = kv if pages is None else dict(kv, pages=pages)
+        forest = generate_draft_forest(draft_model, params, cache, pending,
+                                       dpos, L, J, key, vhat=vhat,
+                                       keep_windows=keep_windows)
+        return dataclasses.replace(forest, cache=strip_view(forest.cache))
+
+    def tree_verify_step(params, kv, pages, window, wmask, wdepth, tpos, key,
+                         tokens, parents, depth, probs, paths, q_idx, q_val,
+                         draft_len):
+        _record("tree_verify", tokens.shape[0], paths.shape[2])
+        cache = kv if pages is None else dict(kv, pages=pages)
+        logits, cache = target_model.forward_window(
+            params, window, cache, tpos, window_mask=wmask,
+            window_depth=wdepth)
+        res = verify_tree(key, tokens, parents, depth, probs, paths, logits,
+                          q_idx, q_val, draft_len)
+        return res, strip_view(cache)
+
+    def tree_commit_step(t_kv, t_pages, d_kv, d_pages, windows, paths,
+                         winner, accept_counts, frozen, tpos, dpos):
+        _record("tree_commit", paths.shape[0], paths.shape[2])
+        t_cache = t_kv if t_pages is None else dict(t_kv, pages=t_pages)
+        d_cache = d_kv if d_pages is None else dict(d_kv, pages=d_pages)
+        t_cache, d_cache = tree_scatter_commit(
+            t_cache, d_cache, windows, paths, winner, accept_counts, frozen,
+            tpos, dpos)
+        return strip_view(t_cache), strip_view(d_cache)
+
     if mode == "eager":
         return RoundSteps(mode=mode, draft=None, verify=None,
                           commit=commit_step)
@@ -166,4 +248,11 @@ def build_round_steps(target_model, draft_model, *, mode: str,
         verify=jax.jit(verify_step,
                        donate_argnums=(1,) if donate else ()),
         commit=jax.jit(commit, donate_argnums=(0, 1, 2) if donate else ()),
+        forest=jax.jit(forest_step,
+                       static_argnames=("L", "J", "vhat", "keep_windows"),
+                       donate_argnums=(1,) if donate else ()),
+        tree_verify=jax.jit(tree_verify_step,
+                            donate_argnums=(1,) if donate else ()),
+        tree_commit=jax.jit(tree_commit_step,
+                            donate_argnums=(0, 2) if donate else ()),
     )

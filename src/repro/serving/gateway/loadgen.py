@@ -33,7 +33,7 @@ import dataclasses
 import random
 import time
 
-from repro.serving.gateway.client import GatewayClient
+from repro.serving.gateway.client import GatewayClient, GatewayError
 
 
 def percentile(xs, q: float) -> float:
@@ -118,7 +118,7 @@ async def _one_request(client: GatewayClient, cfg: LoadGenConfig,
     except asyncio.TimeoutError:
         rec.error = "timeout"
         return rec
-    except (OSError, ConnectionError) as e:
+    except (OSError, ConnectionError, GatewayError) as e:
         rec.error = f"{type(e).__name__}: {e}"
         return rec
     rec.rid = res.rid

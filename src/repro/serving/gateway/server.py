@@ -40,7 +40,9 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import json
+import sys
 import time
+import traceback
 import uuid
 from collections import deque
 
@@ -124,6 +126,10 @@ class MultiSpinGateway:
         self._stepped = False        # first round executed (barrier latch)
         self._server: asyncio.AbstractServer | None = None
         self._step_task: asyncio.Task | None = None
+        # the exception of the round that stopped the step loop, if any:
+        # open streams get an ``error`` event and new requests a 500 that
+        # carry it, so a failed round never reads as client timeouts
+        self.round_error: BaseException | None = None
         self.port = self.config.port
         # span tracing: the gateway owns the process-global tracer for its
         # lifetime (installed in start, uninstalled in stop) so cell/engine/
@@ -238,13 +244,31 @@ class MultiSpinGateway:
                     pass
                 continue
             self._stepped = True
-            if self.config.step_in_thread:
-                rec = await loop.run_in_executor(None, self.cell.step)
-            else:
-                rec = self.cell.step()
+            try:
+                if self.config.step_in_thread:
+                    rec = await loop.run_in_executor(None, self.cell.step)
+                else:
+                    rec = self.cell.step()
+            except Exception as exc:
+                self._fail_rounds(exc)
+                return
             self._dispatch_round(rec)
             # yield so per-connection writers flush before the next round
             await asyncio.sleep(0)
+
+    def _fail_rounds(self, exc: BaseException):
+        """A round raised: stop stepping, and end every open stream with
+        the cause instead of leaving its client to time out."""
+        self.round_error = exc
+        self._running = False
+        traceback.print_exception(exc, file=sys.stderr)
+        detail = self._round_error_detail()
+        for st in list(self._streams.values()):
+            st.push("error", {"rid": st.rid, "error": "round_failed",
+                              "detail": detail}, terminal=True)
+
+    def _round_error_detail(self) -> str:
+        return f"{type(self.round_error).__name__}: {self.round_error}"
 
     def _round_tokens(self, st: _Stream, produced: int) -> list[int]:
         """The tokens to stream this round: real committed ids when the
@@ -432,6 +456,11 @@ class MultiSpinGateway:
         return req, fields.get("tag")
 
     async def _handle_generate(self, reader, writer, body: bytes):
+        if self.round_error is not None:
+            await self._respond(writer, 500, {
+                "error": "round_failed",
+                "detail": self._round_error_detail()})
+            return
         try:
             req, tag = self._parse_generate(body)
         except ValueError as e:

@@ -41,15 +41,10 @@ from repro.core.drafting import generate_draft_forest, generate_drafts
 from repro.core.token_tree import TreeScratch, build_token_tree
 from repro.core.verification import verify_drafts, verify_tree
 from repro.models import build_model
-from repro.models.layers import gather_kv_window, scatter_kv_window
 from repro.models.transformer import strip_view
 from repro.obs import trace
 
-from .compiled import (
-    COMPILE_MODES,
-    build_round_steps,
-    setup_compilation_cache,
-)
+from .compiled import COMPILE_MODES, build_round_steps, tree_scatter_commit
 from .kv_cache import (
     PagedKVCache,
     PagePoolExhausted,
@@ -138,8 +133,7 @@ class SpecEngine:
                  max_len: int = 512, cache_dtype=jnp.float32,
                  cache_kind: str = "contiguous", page_size: int = 16,
                  num_pages: int | None = None, tree_commit: str = "scatter",
-                 compile_mode: str | None = None,
-                 compile_cache: str | None = None):
+                 compile_mode: str | None = None):
         assert target_cfg.vocab_size == draft_cfg.vocab_size, \
             "SLM/LLM pair must share a vocabulary"
         if cache_kind not in CACHE_KINDS:
@@ -188,8 +182,6 @@ class SpecEngine:
         # actually TRACED — the hook fires from inside the jitted bodies, so
         # it counts retraces, not calls; ``warmup()`` pre-seeds the buckets.
         self.compile_mode = compile_mode
-        if compile_mode != "eager":
-            setup_compilation_cache(compile_cache)
         self.step_shapes: set[tuple] = set()
         self.on_step_trace = None
         self._steps = build_round_steps(self.target, self.draft,
@@ -222,9 +214,13 @@ class SpecEngine:
     # ------------------------------------------------------------------
 
     def init_params(self, key):
+        """Random weights for both models, each built by one jitted program
+        directly in its config's ``param_dtype``: the device never holds a
+        float32 or per-layer copy beside them.  Same values as the eager
+        ``init``."""
         kt, kd = jax.random.split(key)
-        self.t_params = self.target.init(kt)
-        self.d_params = self.draft.init(kd)
+        self.t_params = jax.jit(self.target.init)(kt)
+        self.d_params = jax.jit(self.draft.init)(kd)
         return self.t_params, self.d_params
 
     def start(self, prompts: jax.Array) -> StreamState:
@@ -413,13 +409,13 @@ class SpecEngine:
         Call after ``start()``.  Each bucket runs one draft + verify +
         commit step with dummy inputs at EXACTLY the shapes/dtypes the real
         dispatch uses, so serving never pays a trace+compile mid-round
-        (gateway cold starts measured ~minutes at real shapes in PR 1);
-        with ``setup_compilation_cache`` installed the executables also
-        persist across process restarts.  Returns ``(state, info)`` where
-        ``info`` maps each bucket to its warmup seconds — callers MUST
-        adopt the returned state: in ``jit+donate`` mode the commit warmup
-        donates the state arrays (it is a no-op commit: every slot skipped,
-        values unchanged).
+        (gateway cold starts take minutes at real shapes); with
+        ``setup_compilation_cache`` installed by the entry point the
+        executables also persist across process restarts.  Returns
+        ``(state, info)`` where ``info`` maps each bucket to its warmup
+        seconds — callers MUST adopt the returned state: in ``jit+donate``
+        mode the commit warmup donates the state arrays (it is a no-op
+        commit: every slot skipped, values unchanged).
 
         Paged engines warm against the REAL pools under an all--1 page
         table — window writes are dropped, so the donated pool comes back
@@ -469,15 +465,6 @@ class SpecEngine:
             jax.block_until_ready((pend, emission))
             info[(n, L)] = time.perf_counter() - t0
         return state, info
-
-    # ------------------------------------------------------------------
-
-    def _paged_views(self, B: int):
-        """Per-round cache views: pools + page tables for rows [0, B)."""
-        rows = range(B)
-        t = dict(self.t_cache, pages=self.t_pages.device_table(rows))
-        d = dict(self.d_cache, pages=self.d_pages.device_table(rows))
-        return t, d
 
     # ------------------------------------------------------------------
     # round steps (reusable on row subsets — the continuous engine's core)
@@ -828,19 +815,34 @@ class SpecEngine:
                         self.t_pages.truncate(b, t_len)
                         self.d_pages.truncate(b, d_len)
                     raise
-            t_cache, d_cache = self._paged_views(B)
+            t_pt = self.t_pages.device_table(range(B))
+            d_pt = self.d_pages.device_table(range(B))
         else:
-            t_cache, d_cache = self.t_cache, self.d_cache
+            t_pt = d_pt = None
+        # compiled path: the forest, the target pass + accept test and the
+        # scatter-commit each run as ONE jitted call per (B, J, L) bucket
+        # with the KV pools passed (and in jit+donate mode donated) as
+        # arguments — the caches are adopted right after each call
+        steps = self._steps if self._steps.forest is not None else None
+        view = lambda kv, pt: kv if pt is None else dict(kv, pages=pt)
 
         # --- step 2: J drafting runs per stream (SLM) ---
         scatter = self.tree_commit == "scatter" and J > 1
         with _span("engine.draft_forest", {"B": B, "L": L, "J": J}) as sp:
-            forest = generate_draft_forest(self.draft, self.d_params, d_cache,
-                                           state.pending, state.draft_pos,
-                                           L, J, k_draft, vhat=vhat,
-                                           keep_windows=scatter)
+            if steps is not None:
+                forest = steps.forest(self.d_params, self.d_cache, d_pt,
+                                      state.pending, state.draft_pos,
+                                      k_draft, L=L, J=J, vhat=vhat,
+                                      keep_windows=scatter)
+                self.d_cache = forest.cache
+                forest = dataclasses.replace(forest, cache=None)
+            else:
+                forest = generate_draft_forest(
+                    self.draft, self.d_params, view(self.d_cache, d_pt),
+                    state.pending, state.draft_pos, L, J, k_draft,
+                    vhat=vhat, keep_windows=scatter)
+                self.d_cache = strip_view(forest.cache)
             sp.attach(forest.tokens)
-        d_cache = forest.cache
 
         # --- pack into the prefix-deduplicated tree (host-side) ---
         with _span("engine.tree_build", {"B": B, "L": L, "J": J}):
@@ -857,23 +859,32 @@ class SpecEngine:
             wmask = jax.device_put(ttree.window_mask())
             wdepth = jax.device_put(ttree.window_depth().astype(np.int32))
 
-        # --- step 4: ONE ancestor-masked target pass over the whole tree ---
-        with _span("engine.target_pass", {"B": B, "W": W + 1, "J": J}) as sp:
-            logits, t_cache = self.target.forward_window(
-                self.t_params, window, t_cache, state.target_pos,
-                window_mask=wmask, window_depth=wdepth)
-            sp.attach(logits)
+        tree_args = tuple(jnp.asarray(a) for a in (
+            ttree.tokens, ttree.parents, ttree.depth, ttree.probs,
+            ttree.paths))
+        q_args = (jnp.asarray(ttree.q_idx), jnp.asarray(ttree.q_val),
+                  jnp.asarray(lengths, jnp.int32))
 
-        with _span("engine.verify_tokens", {"B": B, "L": L, "J": J}) as sp:
-            res = verify_tree(k_verify, jnp.asarray(ttree.tokens),
-                              jnp.asarray(ttree.parents),
-                              jnp.asarray(ttree.depth),
-                              jnp.asarray(ttree.probs),
-                              jnp.asarray(ttree.paths), logits,
-                              jnp.asarray(ttree.q_idx),
-                              jnp.asarray(ttree.q_val),
-                              jnp.asarray(lengths, jnp.int32))
-            sp.attach(res.accept_counts)
+        # --- step 4: ONE ancestor-masked target pass over the whole tree ---
+        if steps is not None:
+            with _span("engine.target_pass",
+                       {"B": B, "W": W + 1, "J": J}) as sp:
+                res, self.t_cache = steps.tree_verify(
+                    self.t_params, self.t_cache, t_pt, window, wmask, wdepth,
+                    state.target_pos, k_verify, *tree_args, *q_args)
+                sp.attach(res.accept_counts)
+        else:
+            with _span("engine.target_pass",
+                       {"B": B, "W": W + 1, "J": J}) as sp:
+                logits, t_cache = self.target.forward_window(
+                    self.t_params, window, view(self.t_cache, t_pt),
+                    state.target_pos, window_mask=wmask, window_depth=wdepth)
+                self.t_cache = strip_view(t_cache)
+                sp.attach(logits)
+            with _span("engine.verify_tokens",
+                       {"B": B, "L": L, "J": J}) as sp:
+                res = verify_tree(k_verify, *tree_args, logits, *q_args)
+                sp.attach(res.accept_counts)
 
         # --- step 5a: land the accepted path's K/V (a J=1 chain already IS
         # the sequential window: nothing to move)
@@ -885,30 +896,21 @@ class SpecEngine:
             # rows from their tree-window slots into the committed slots —
             # no repair forward, and no host sync on accept_counts
             with _span("engine.kv_commit", {"B": B, "L": L, "J": J}) as sp:
-                path_w = jnp.take_along_axis(
-                    jnp.asarray(ttree.paths), res.winner[:, None, None],
-                    axis=1)[:, 0]                              # (B, L)
-                keep = ((jnp.arange(L)[None, :] < res.accept_counts[:, None])
-                        & (path_w >= 0) & ~frz[:, None])
-                col = jnp.arange(L, dtype=jnp.int32)[None, :]
-                src_t = state.target_pos[:, None] + 1 + jnp.maximum(path_w, 0)
-                dst_t = state.target_pos[:, None] + 1 + col
-                dst_d = state.draft_pos[:, None] + 1 + col
-                t_pt = t_cache.get("pages")
-                d_pt = d_cache.get("pages")
-                for leaf in ("k", "v", "dense_k", "dense_v"):
-                    if leaf in forest.windows:
-                        vals = gather_kv_window(t_cache[leaf], src_t,
-                                                page_table=t_pt)
-                        t_cache[leaf] = scatter_kv_window(
-                            t_cache[leaf], vals, dst_t, keep, page_table=t_pt)
-                        win = jnp.take_along_axis(
-                            forest.windows[leaf],
-                            res.winner[None, :, None, None, None, None],
-                            axis=2)[:, :, 0]                   # (Ln,B,L,KV,D)
-                        d_cache[leaf] = scatter_kv_window(
-                            d_cache[leaf], win, dst_d, keep, page_table=d_pt)
-                sp.attach(t_cache["k"])
+                if steps is not None:
+                    self.t_cache, self.d_cache = steps.tree_commit(
+                        self.t_cache, t_pt, self.d_cache, d_pt,
+                        forest.windows, tree_args[4], res.winner,
+                        res.accept_counts, frz, state.target_pos,
+                        state.draft_pos)
+                else:
+                    t_cache, d_cache = tree_scatter_commit(
+                        view(self.t_cache, t_pt), view(self.d_cache, d_pt),
+                        forest.windows, tree_args[4], res.winner,
+                        res.accept_counts, frz, state.target_pos,
+                        state.draft_pos)
+                    self.t_cache = strip_view(t_cache)
+                    self.d_cache = strip_view(d_cache)
+                sp.attach(self.t_cache["k"])
         elif J > 1:
             # repair forward (kept as the reference path, and for targets
             # whose window pass cannot donate K/V): one plain causal window
@@ -919,12 +921,14 @@ class SpecEngine:
                 axis=1)                                        # (B, n_max+1)
             with _span("engine.cache_repair", {"B": B, "n": n_max + 1}) as sp:
                 _, t_cache = self.target.forward_window(
-                    self.t_params, repair, t_cache, state.target_pos)
+                    self.t_params, repair, view(self.t_cache, t_pt),
+                    state.target_pos)
                 _, d_cache = self.draft.forward_window(
-                    self.d_params, repair, d_cache, state.draft_pos)
-                sp.attach(t_cache)
-        self.t_cache = strip_view(t_cache)
-        self.d_cache = strip_view(d_cache)
+                    self.d_params, repair, view(self.d_cache, d_pt),
+                    state.draft_pos)
+                self.t_cache = strip_view(t_cache)
+                self.d_cache = strip_view(d_cache)
+                sp.attach(self.t_cache)
 
         # --- step 5b: commit + rollback (identical to the sequential round)
         adv = jnp.where(frz, 0, 1 + res.accept_counts)

@@ -231,17 +231,19 @@ def test_elastic_resharding_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         import sys; sys.path.insert(0, "src")
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.distributed.fault_tolerance import degraded_mesh_plan
 
+        auto = (AxisType.Auto, AxisType.Auto)
         w = np.arange(48.0, dtype=np.float32).reshape(8, 6)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
         sharded = jax.device_put(w, NamedSharding(mesh, P("data", "model")))
         plan = degraded_mesh_plan((4, 2), ("data", "model"), failed_chips=2,
                                   chips_per_host=2)
         assert plan.shape == (3, 2), plan.shape
         new_mesh = jax.make_mesh(plan.shape, plan.axis_names,
-                                 devices=np.array(jax.devices()[:6]))
+                                 devices=np.array(jax.devices()[:6]),
+                                 axis_types=auto)
         # checkpoint-restore path: host roundtrip then re-place
         host = np.asarray(sharded)
         resharded = jax.device_put(host, NamedSharding(new_mesh, P(None, "model")))

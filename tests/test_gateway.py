@@ -226,6 +226,33 @@ def test_unservable_request_rejected_with_422():
     assert err.body["error"] == "unservable"
 
 
+def test_failed_round_ends_streams_with_its_cause():
+    """A round that raises stops the step loop; the open stream gets an
+    ``error`` event carrying the exception and later requests a 500, so
+    clients see the cause at once instead of timing out."""
+
+    def broken_step():
+        raise RuntimeError("verify pass blew up")
+
+    async def run():
+        cell = _cell(max_batch=2)
+        cell.step = broken_step
+        gw, cli = await _start(cell)
+        res = await asyncio.wait_for(
+            cli.generate(prompt_len=8, max_new_tokens=4), timeout=10)
+        with pytest.raises(GatewayError) as exc:
+            await cli.generate(prompt_len=8, max_new_tokens=4)
+        await gw.stop()
+        return gw, res, exc.value
+
+    gw, res, err = asyncio.run(run())
+    assert isinstance(gw.round_error, RuntimeError)
+    assert res.error == "round_failed" and not res.tokens
+    assert err.status == 500
+    assert err.body == {"error": "round_failed",
+                        "detail": "RuntimeError: verify pass blew up"}
+
+
 # ---------------------------------------------------------------------------
 # explicit stream retirement (DELETE) on the synthetic backend
 # ---------------------------------------------------------------------------

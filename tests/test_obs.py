@@ -139,6 +139,27 @@ def test_ring_is_bounded_and_counts_drops():
     assert tracer.snapshot() == [] and tracer.dropped == 0
 
 
+@pytest.mark.parametrize("device_sync", [False, True])
+def test_recorded_spans_hold_no_attached_value(device_sync):
+    """A span's attached value is only blocked on at exit: the ring must not
+    keep it alive, or every traced round would pin its device buffers."""
+    import gc
+    import weakref
+
+    import jax.numpy as jnp
+
+    tracer = trace.Tracer(device_sync=device_sync)
+    with trace.tracing(tracer):
+        value = jnp.ones((4, 4))
+        ref = weakref.ref(value)
+        with trace.span("s") as sp:
+            sp.attach(value)
+        del value
+        gc.collect()
+    assert ref() is None
+    assert [sp.name for sp in tracer.snapshot()] == ["s"]
+
+
 def test_totals_aggregate_matches_snapshot():
     with trace.tracing() as tr:
         for _ in range(3):

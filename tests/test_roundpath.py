@@ -8,13 +8,15 @@ not a tolerance.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.api import CellConfig, EngineBackend, MultiSpinCell, Request
 from repro.configs import get_config
+from repro.models import build_model
 from repro.serving import SpecEngine
-from repro.serving.compiled import COMPILE_MODES
+from repro.serving.compiled import COMPILE_MODES, build_round_steps
 
 B, L, VHAT = 3, 4, 64
 MAX_LEN = 96
@@ -74,13 +76,62 @@ def test_compiled_bit_identical_to_eager(mode, cache_kind):
 
 
 def test_mixed_width_rounds_bit_identical():
-    """J=1 rounds run the compiled steps; J>1 rounds take the tree path
-    (eager dispatch).  Alternating them through one engine must still match
-    eager exactly — the caches the jitted steps adopt and the ones the tree
-    path rebuilds have to interoperate."""
+    """J=1 rounds run the linear compiled steps; J>1 rounds run the tree
+    steps (forest, tree verify, scatter commit).  Alternating them through
+    one engine must still match eager exactly — the caches each kind of
+    step donates and adopts have to interoperate."""
     widths = (1, 2, 1, 2)
     assert _committed("jit+donate", "paged", widths) \
         == _committed("eager", "paged", widths)
+
+
+@pytest.mark.parametrize("mode", ["jit", "jit+donate"])
+def test_compiled_tree_rounds_bit_identical_to_eager(mode):
+    assert _committed(mode, "paged", (2,)) == _committed("eager", "paged",
+                                                         (2,))
+
+
+def test_tree_rounds_trace_once_per_bucket():
+    """A tree round runs three jitted steps per (B, J, L) bucket; repeating
+    the bucket must not retrace them."""
+    eng, prompts = _engine("jit+donate")
+    st = eng.start(prompts)
+    base = jax.random.PRNGKey(3)
+    st, _, _ = eng.spin_round(st, np.full(B, L), base, vhat=VHAT,
+                              draft_width=2)
+    assert {k for k in eng.step_shapes if k[0] != "commit"} == {
+        ("forest", B, L), ("tree_verify", B, L), ("tree_commit", B, L)}
+    retraced = []
+    eng.on_step_trace = retraced.append
+    for r in range(2):
+        st, _, _ = eng.spin_round(st, np.full(B, L),
+                                  jax.random.fold_in(base, r + 1),
+                                  vhat=VHAT, draft_width=2)
+    assert retraced == [], f"retraced: {retraced}"
+
+
+@pytest.mark.parametrize("L_step", [1, L])
+def test_donated_draft_step_updates_pools_in_place(L_step):
+    """The draft step runs L+1 window passes over the donated pool; each
+    layer writes its slice back into the carried stack, so the compiled
+    step needs less scratch than one copy of the pool.  (Scanned over as
+    inputs, the pool came back as a fresh copy per pass: several pools of
+    temporaries, more than a full-width pair leaves free on one chip.)"""
+    model = build_model(get_config("qwen3.5-0.8b").smoke())
+    steps = build_round_steps(model, model, mode="jit+donate")
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    pool = jax.eval_shape(lambda: model.init_paged_cache(512, 16,
+                                                         jnp.float32))
+    pt = jax.ShapeDtypeStruct((B, 32), np.int32)
+    iv = jax.ShapeDtypeStruct((B,), np.int32)
+    compiled = steps.draft.lower(params, pool, pt, iv, iv,
+                                 jax.random.PRNGKey(0), L=L_step,
+                                 vhat=VHAT).compile()
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes, (mem.temp_size_in_bytes,
+                                                 pool_bytes)
 
 
 @pytest.mark.parametrize("mode", COMPILE_MODES)
