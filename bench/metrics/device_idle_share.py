@@ -1,0 +1,12 @@
+"""1 - (union of device-operation intervals / traced window), in percent."""
+
+LAYER = "device"
+SOURCE = "device_trace"
+MOVES = "goodput_tok_s"
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
