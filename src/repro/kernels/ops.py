@@ -42,8 +42,8 @@ def _span(name: str, x):
     backend actually dispatched (pallas | interpret | ref).  The args dict
     is only built when a tracer is installed, so untraced dispatch pays a
     single function call (``trace.NULL_SPAN``) and nothing else.  Span
-    durations measure DISPATCH wall time unless the installed tracer has
-    ``device_sync=True`` and the call site attaches its output."""
+    durations measure DISPATCH wall time (under ``jit``: trace time); the
+    device's time is in a profiler trace (``Tracer(profiler=True)``)."""
     if trace.active() is None:
         return trace.NULL_SPAN
     backend = ("interpret" if _interpret()
@@ -75,7 +75,7 @@ def ssd_scan(x, dt, A, B, C, chunk: int = 64, initial_state=None):
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    with _span("ops.ssd_scan", x) as sp:
+    with _span("ops.ssd_scan", x):
         if _use_pallas():
             from .ssd_scan import ssd_scan_pallas
             y, fs = ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
@@ -84,15 +84,13 @@ def ssd_scan(x, dt, A, B, C, chunk: int = 64, initial_state=None):
         else:
             y, fs = ref.ssd_scan_ref(x, dt, A, B, C, chunk=chunk,
                                      initial_state=initial_state)
-        sp.attach(y)
     return (y[:, :s] if pad else y), fs
 
 
 def ssd_decode(x, dt, A, B, C, state):
     """One-token SSD recurrence (cheap; always the jnp formulation)."""
-    with _span("ops.ssd_decode", x) as sp:
+    with _span("ops.ssd_decode", x):
         out = ref.ssd_decode_ref(x, dt, A, B, C, state)
-        sp.attach(out[0])
     return out
 
 
@@ -102,27 +100,25 @@ def ssd_decode(x, dt, A, B, C, state):
 
 def flash_attention(q, k, v, causal: bool = True):
     """Causal GQA attention. q: (B,Sq,H,D), k/v: (B,Skv,KV,D)."""
-    with _span("ops.flash_attention", q) as sp:
+    with _span("ops.flash_attention", q):
         if _use_pallas():
             from .flash_attention import flash_attention_pallas
             out = flash_attention_pallas(q, k, v, causal=causal,
                                          interpret=_interpret())
         else:
             out = ref.flash_attention_ref(q, k, v, causal=causal)
-        sp.attach(out)
     return out
 
 
 def decode_attention(q, k_cache, v_cache, lengths):
     """Single-step decode attention against a KV cache."""
-    with _span("ops.decode_attention", q) as sp:
+    with _span("ops.decode_attention", q):
         if _use_pallas():
             from .decode_attention import decode_attention_pallas
             out = decode_attention_pallas(q, k_cache, v_cache, lengths,
                                           interpret=_interpret())
         else:
             out = ref.decode_attention_ref(q, k_cache, v_cache, lengths)
-        sp.attach(out)
     return out
 
 
@@ -133,7 +129,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths):
     (-1 = unmapped); lengths: (B,) valid kv count for query row 0 (row t
     attends [0, lengths + t)).
     """
-    with _span("ops.paged_attention", q) as sp:
+    with _span("ops.paged_attention", q):
         if _use_pallas():
             from .paged_attention import paged_attention_pallas
             out = paged_attention_pallas(q, k_pool, v_pool, page_table,
@@ -141,7 +137,6 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths):
         else:
             out = ref.paged_attention_ref(q, k_pool, v_pool, page_table,
                                           lengths)
-        sp.attach(out)
     return out
 
 
@@ -153,7 +148,7 @@ def tree_attention(q, k_cache, v_cache, lengths, win_mask):
     [lengths, lengths + T); win_mask: (B, T, T) ancestor-or-self matrix.
     A lower-triangular win_mask recovers the sequential causal window.
     """
-    with _span("ops.tree_attention", q) as sp:
+    with _span("ops.tree_attention", q):
         if _use_pallas():
             from .tree_attention import tree_attention_pallas
             out = tree_attention_pallas(q, k_cache, v_cache, lengths,
@@ -161,14 +156,13 @@ def tree_attention(q, k_cache, v_cache, lengths, win_mask):
         else:
             out = ref.tree_attention_ref(q, k_cache, v_cache, lengths,
                                          win_mask)
-        sp.attach(out)
     return out
 
 
 def paged_tree_attention(q, k_pool, v_pool, page_table, lengths, win_mask):
     """``tree_attention`` through a paged KV cache (scalar-prefetched page
     table; pools (P, ps, KV, D), page_table (B, n_slots), -1 = unmapped)."""
-    with _span("ops.paged_tree_attention", q) as sp:
+    with _span("ops.paged_tree_attention", q):
         if _use_pallas():
             from .tree_attention import paged_tree_attention_pallas
             out = paged_tree_attention_pallas(q, k_pool, v_pool, page_table,
@@ -177,13 +171,12 @@ def paged_tree_attention(q, k_pool, v_pool, page_table, lengths, win_mask):
         else:
             out = ref.paged_tree_attention_ref(q, k_pool, v_pool, page_table,
                                                lengths, win_mask)
-        sp.attach(out)
     return out
 
 
 def decode_attention_q8(q, k_cache, v_cache, k_scale, v_scale, lengths):
     """Decode attention over an int8 KV cache (per-head scales)."""
-    with _span("ops.decode_attention_q8", q) as sp:
+    with _span("ops.decode_attention_q8", q):
         if _use_pallas():
             from .decode_attention import decode_attention_q8_pallas
             out = decode_attention_q8_pallas(q, k_cache, v_cache, k_scale,
@@ -193,7 +186,6 @@ def decode_attention_q8(q, k_cache, v_cache, k_scale, v_scale, lengths):
             out = ref.decode_attention_quantized_ref(q, k_cache, v_cache,
                                                      k_scale, v_scale,
                                                      lengths)
-        sp.attach(out)
     return out
 
 
@@ -203,26 +195,24 @@ def decode_attention_q8(q, k_cache, v_cache, k_scale, v_scale, lengths):
 
 def gather_softmax_prob(logits, token_ids):
     """p_target(token) for each row without materializing softmax(V)."""
-    with _span("ops.gather_softmax_prob", logits) as sp:
+    with _span("ops.gather_softmax_prob", logits):
         if _use_pallas():
             from .gather_softmax_prob import gather_softmax_prob_pallas
             out = gather_softmax_prob_pallas(logits, token_ids,
                                              interpret=_interpret())
         else:
             out = ref.gather_softmax_prob_ref(logits, token_ids)
-        sp.attach(out)
     return out
 
 
 def residual_sample(p, q, u):
     """Sample from normalize(max(p-q, 0)) via inverse CDF (paper eq. 5)."""
-    with _span("ops.residual_sample", p) as sp:
+    with _span("ops.residual_sample", p):
         if _use_pallas():
             from .residual_sample import residual_sample_pallas
             out = residual_sample_pallas(p, q, u, interpret=_interpret())
         else:
             out = ref.residual_sample_ref(p, q, u)
-        sp.attach(out)
     return out
 
 
@@ -246,7 +236,7 @@ def fused_verify_sample(target_logits, draft_tokens, draft_probs, q_idx,
     B, L = draft_tokens.shape
     if draft_len is None:
         draft_len = jnp.full((B,), L, jnp.int32)
-    with _span("ops.fused_verify_sample", target_logits) as sp:
+    with _span("ops.fused_verify_sample", target_logits):
         if _use_pallas():
             from .fused_verify_sample import fused_verify_sample_pallas
             out = fused_verify_sample_pallas(target_logits, draft_tokens,
@@ -257,5 +247,4 @@ def fused_verify_sample(target_logits, draft_tokens, draft_probs, q_idx,
             out = ref.fused_verify_sample_ref(target_logits, draft_tokens,
                                               draft_probs, q_idx, q_val,
                                               u_accept, u_resid, draft_len)
-        sp.attach(out[2])
     return out

@@ -102,10 +102,19 @@ async def _serve(args):
     from repro.serving.gateway import GatewayConfig, MetricsHub, serve
 
     cell = build_cell(args)
+    if args.profiler_port:
+        # a profile captured from this port shows the obs: spans of the
+        # tracer installed here over the device's operations
+        import jax
+
+        from repro.obs import trace
+
+        jax.profiler.start_server(args.profiler_port)
+        trace.install(trace.Tracer(profiler=True))
+        print(f"  profiler server on port {args.profiler_port}")
     hub = MetricsHub(trace_path=args.trace)
     gcfg = GatewayConfig(host=args.host, port=args.port,
-                         trace_spans=args.trace_spans,
-                         trace_device_sync=args.trace_device_sync)
+                         trace_spans=args.trace_spans)
     print(f"multi-spin gateway: scheme={args.scheme} backend={args.backend} "
           f"max_batch={args.max_batch}")
     print(f"  GET  http://{args.host}:{args.port}/              (dashboard)")
@@ -184,9 +193,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--trace-spans", action="store_true",
                     help="install the span tracer; GET /v1/trace serves "
                          "Chrome trace-event JSON for Perfetto")
-    ap.add_argument("--trace-device-sync", action="store_true",
-                    help="block_until_ready at span exits so device time "
-                         "lands in the enclosing span (slower rounds)")
+    ap.add_argument("--profiler-port", type=int, default=0, metavar="N",
+                    help="start a JAX profiler server on port N and install "
+                         "a span tracer whose spans land in its traces as "
+                         "obs:<name>, beside the device's operations")
     ap.add_argument("--smoke", action="store_true",
                     help="no server: in-process loadgen burst, print report")
     ap.add_argument("--smoke-requests", type=int, default=8)

@@ -14,13 +14,20 @@ Design goals, in priority order:
 3. **Bounded memory.**  Finished spans land in a ``deque(maxlen=capacity)``
    ring: a long-lived gateway with tracing left on degrades to "last N
    spans", never to OOM.
-4. **Honest device timing, opt-in.**  JAX dispatch is asynchronous — the
-   wall-clock around an ``ops.*`` call measures dispatch, not compute.
-   A ``Tracer(device_sync=True)`` calls ``jax.block_until_ready`` on the
-   value attached to each span (``sp.attach(out)``) before closing it, so
-   span durations become device-true.  The import of jax is lazy and only
-   happens when device sync is actually enabled, keeping this module (and
-   the gateway importing it) jax-free.
+4. **On the profiler's clock, opt-in.**  JAX dispatch is asynchronous:
+   the wall clock around a dispatch measures the enqueue, not the device's
+   work.  A ``Tracer(profiler=True)`` also opens a
+   ``jax.profiler.TraceAnnotation("obs:<name>")`` for every span, so that
+   under an active profiler session each span lands on the trace's host
+   plane, on the same clock as the device's operations; device time is
+   read from that trace, never by blocking here.  JAX is imported lazily
+   and only for such a tracer, keeping this module (and the gateway
+   importing it) jax-free.
+5. **JAX's own compile work.**  While a tracer is installed, JAX's
+   tracing, lowering and compiling (or cache loading) of each program land
+   as ``jax.trace`` / ``jax.lower`` / ``jax.compile`` intervals with the
+   program's ``fun_name``, through one ``jax.monitoring`` listener that the
+   first ``install`` registers when JAX is already imported.
 
 Usage::
 
@@ -29,7 +36,7 @@ Usage::
     tracer = trace.install(trace.Tracer())
     with trace.span("cell.step", cat="cell") as sp:
         ...
-        sp.set(rounds=3)          # attach args at exit
+        sp.set(rounds=3)          # args merged at exit
     json_dict = tracer.export_chrome_trace()   # load in Perfetto
     trace.uninstall()
 
@@ -42,6 +49,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -76,21 +84,16 @@ class _NullSpan:
     def set(self, **args):
         pass
 
-    def attach(self, value):
-        pass
-
 
 NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One live span.  Use as a context manager; the tracer records it on
-    exit.  ``set(**args)`` merges key/values into the exported ``args``;
-    ``attach(value)`` hands the tracer a jax value to block on at exit when
-    device sync is enabled (no-op otherwise)."""
+    """One span.  Use as a context manager; the tracer records it on exit.
+    ``set(**args)`` merges key/values into the exported ``args``."""
 
     __slots__ = ("tracer", "name", "cat", "args", "sid", "parent_sid",
-                 "tid", "t0_ns", "dur_ns", "_sync")
+                 "tid", "t0_ns", "dur_ns", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict | None):
@@ -103,18 +106,12 @@ class Span:
         self.tid = 0
         self.t0_ns = 0
         self.dur_ns = 0
-        self._sync = None
+        self._annotation = None
 
     def set(self, **args):
         if self.args is None:
             self.args = {}
         self.args.update(args)
-
-    def attach(self, value):
-        # kept only to block on at exit: a recorded span must not hold a
-        # device buffer (a KV pool, logits) for as long as the ring keeps it
-        if self.tracer.device_sync:
-            self._sync = value
 
     def __enter__(self):
         self.tracer._enter(self)
@@ -129,21 +126,26 @@ class Tracer:
     """Bounded, thread-safe span recorder with Chrome-trace export.
 
     ``capacity`` bounds the retained finished spans (a ring — oldest spans
-    fall off first).  ``device_sync=True`` makes span exits call
-    ``jax.block_until_ready`` on each span's attached value, turning
-    dispatch timings into device timings (lazy jax import; only pay for it
-    if you ask)."""
+    fall off first).  ``profiler=True`` also opens a
+    ``jax.profiler.TraceAnnotation("obs:<name>")`` for each span, so that
+    spans appear in a profiler trace beside the device's operations (lazy
+    jax import; only pay for it if you ask)."""
 
-    def __init__(self, capacity: int = 65536, device_sync: bool = False):
+    ANNOTATION_PREFIX = "obs:"
+
+    def __init__(self, capacity: int = 65536, profiler: bool = False):
         self.capacity = int(capacity)
-        self.device_sync = bool(device_sync)
+        self.profiler = bool(profiler)
         self.spans: deque = deque(maxlen=self.capacity)
         self.dropped = 0
         self._ids = itertools.count()
         self._tls = threading.local()
         self._lock = threading.Lock()
         self.t0_ns = time.perf_counter_ns()
-        self._block = None
+        self._annotate = None
+        if self.profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
         # monotonically increasing named counters (host-transfer accounting:
         # the engine bumps ``engine.host_sync`` at every blocking device
         # fetch) — unbounded only in name count, which instrumentation fixes
@@ -163,21 +165,24 @@ class Tracer:
         sp.parent_sid = st[-1].sid if st else -1
         sp.tid = threading.get_ident()
         st.append(sp)
+        if self._annotate is not None:
+            sp._annotation = self._annotate(self.ANNOTATION_PREFIX + sp.name)
+            sp._annotation.__enter__()
         sp.t0_ns = time.perf_counter_ns()
 
     def _exit(self, sp: Span):
-        if self.device_sync and sp._sync is not None:
-            if self._block is None:
-                import jax
-                self._block = jax.block_until_ready
-            self._block(sp._sync)
-            sp._sync = None
         sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
         st = self._stack()
         if st and st[-1] is sp:
             st.pop()
         elif sp in st:          # tolerate out-of-order exits, never corrupt
             st.remove(sp)
+        self._keep(sp)
+
+    def _keep(self, sp: Span):
         with self._lock:
             if len(self.spans) == self.capacity:
                 self.dropped += 1
@@ -188,6 +193,25 @@ class Tracer:
     def span(self, name: str, cat: str = "repro",
              args: dict | None = None) -> Span:
         return Span(self, name, cat, args)
+
+    def record(self, name: str, t0_ns: int, t1_ns: int, cat: str = "repro",
+               args: dict | None = None) -> Span:
+        """Record a finished interval ``[t0_ns, t1_ns]`` on the
+        ``time.perf_counter_ns`` clock: one that began on another thread (a
+        request's queue wait) or that is reported after the fact (JAX's
+        compile events).  It is filed under the calling thread, as a child
+        of the innermost span open there that began no later than it."""
+        sp = Span(self, name, cat, args)
+        sp.sid = next(self._ids)
+        sp.tid = threading.get_ident()
+        sp.t0_ns = int(t0_ns)
+        sp.dur_ns = max(0, int(t1_ns) - sp.t0_ns)
+        for parent in reversed(self._stack()):
+            if parent.t0_ns <= sp.t0_ns:
+                sp.parent_sid = parent.sid
+                break
+        self._keep(sp)
+        return sp
 
     def incr(self, name: str, n: int = 1) -> None:
         """Bump a named counter (thread-safe).  Counters ride the tracer so
@@ -279,6 +303,7 @@ def install(tracer: Tracer | None = None) -> Tracer:
     ``span()`` call — no re-wiring."""
     global _tracer
     _tracer = tracer if tracer is not None else Tracer()
+    _listen_to_jax()
     return _tracer
 
 
@@ -326,9 +351,60 @@ class tracing:
         global _tracer
         self._prev = _tracer
         _tracer = self.tracer
+        _listen_to_jax()
         return self.tracer
 
     def __exit__(self, *exc):
         global _tracer
         _tracer = self._prev
         return False
+
+
+# ---------------------------------------------------------------------------
+# JAX's compile events, forwarded to whichever tracer is installed
+# ---------------------------------------------------------------------------
+
+JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_jax_listening = False
+
+
+def _listen_to_jax() -> None:
+    """Register the process's one pair of ``jax.monitoring`` listeners, the
+    first time a tracer is installed after JAX was imported (never
+    importing JAX itself).  They stay registered and do nothing while no
+    tracer is installed."""
+    global _jax_listening
+    if _jax_listening or "jax" not in sys.modules:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_on_jax_time_span)
+    monitoring.register_event_listener(_on_jax_event)
+    _jax_listening = True
+
+
+def _on_jax_time_span(event: str, start_time: float, end_time: float,
+                      **kwargs) -> None:
+    t = _tracer
+    name = JAX_EVENTS.get(event)
+    if t is None or name is None:
+        return
+    # JAX times these on the wall clock; move them onto perf_counter_ns by
+    # the two clocks' offset now, as the event is reported
+    offset = time.perf_counter_ns() - time.time_ns()
+    t.record(name, int(start_time * 1e9) + offset,
+             int(end_time * 1e9) + offset, cat="jax",
+             args={"fun_name": kwargs.get("fun_name", "?")})
+    if name == "jax.compile":
+        t.incr("jax.compile")
+
+
+def _on_jax_event(event: str, **kwargs) -> None:
+    t = _tracer
+    if t is not None and event == JAX_CACHE_HIT:
+        t.incr("jax.cache_hit")

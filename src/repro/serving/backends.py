@@ -270,11 +270,10 @@ class EngineBackend:
             "B": B, "K": len(rows), "L_max": int(lengths.max()),
             "J": int(draft_width)}
         h0 = int(getattr(self.engine, "host_syncs", 0))
-        with trace.span("engine.verify", cat="engine", args=args) as sp:
+        with trace.span("engine.verify", cat="engine", args=args):
             self.state, res, _ = self.engine.spin_round(
                 self.state, full, key, vhat=self.vhat, freeze=freeze,
                 draft_width=int(draft_width))
-            sp.attach(res.output_len)
         self.last_round_host_syncs = \
             int(getattr(self.engine, "host_syncs", 0)) - h0
         # the commit's packed emission already landed the accepted counts on

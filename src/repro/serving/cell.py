@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 
 import numpy as np
 
@@ -287,6 +288,8 @@ class MultiSpinCell:
 
     def submit(self, req: Request) -> int:
         """Queue a request (device asking to join the cell)."""
+        if not req.queued_ns:
+            req.queued_ns = time.perf_counter_ns()
         self.scheduler.submit(req)
         return req.rid
 
@@ -296,7 +299,17 @@ class MultiSpinCell:
 
         Backends with an ``can_admit`` hook (the paged engine) gate
         admission on true memory capacity: a join is refused only when the
-        page pool cannot hold the request — it then waits in the queue."""
+        page pool cannot hold the request — it then waits in the queue.
+
+        With a tracer installed the call runs under a ``cell.admit`` span
+        (``n`` admitted), and each admitted request's wait from its queueing
+        to its row bind is recorded as a ``gateway.queue`` interval."""
+        with trace.span("cell.admit", cat="cell") as sp:
+            active, n_new = self._admit()
+            sp.set(n=n_new)
+        return active
+
+    def _admit(self) -> tuple[list[Request], int]:
         # config.max_batch is the single source of truth for capacity (it can
         # be resized at runtime); the scheduler just mirrors it
         self.scheduler.max_batch = self.config.max_batch
@@ -306,9 +319,20 @@ class MultiSpinCell:
         # requests that can NEVER be served are evicted into self.rejected
         # rather than wedging the FIFO
         bind = getattr(self.backend, "bind", None)
+        tracer = trace.active()
+
+        def on_admit(r):
+            if tracer is not None and r.queued_ns:
+                tracer.record("gateway.queue", r.queued_ns,
+                              time.perf_counter_ns(), cat="cell",
+                              args={"rid": int(r.rid)})
+            if bind is not None:
+                bind([r])
+
         active = self.scheduler.admit(
             can_admit=getattr(self.backend, "can_admit", None),
-            on_admit=(lambda r: bind([r])) if bind is not None else None,
+            on_admit=(on_admit if bind is not None or tracer is not None
+                      else None),
             servable=getattr(self.backend, "servable", None),
             on_reject=self._reject)
         n_new = len(active) - before
@@ -321,7 +345,7 @@ class MultiSpinCell:
             if self.estimator is not None:
                 self.estimator.extend(n_new)
             self._emit("on_admit", active[before:])
-        return active
+        return active, n_new
 
     def _reject(self, req: Request):
         """Evict a permanently-unservable request (loudly: recorded AND

@@ -67,7 +67,6 @@ class GatewayConfig:
     default_T_S: float = 0.009
     trace_spans: bool = False      # install a repro.obs tracer for the run
     trace_capacity: int = 65536    # span ring size (oldest spans drop)
-    trace_device_sync: bool = False  # block_until_ready at span exits
 
 
 class _Stream:
@@ -150,8 +149,7 @@ class MultiSpinGateway:
                 self.tracer = existing
             else:
                 self.tracer = trace.install(trace.Tracer(
-                    capacity=self.config.trace_capacity,
-                    device_sync=self.config.trace_device_sync))
+                    capacity=self.config.trace_capacity))
                 self._owns_tracer = True
         self._server = await asyncio.start_server(
             self._handle_connection, self.config.host, self.config.port)
@@ -237,11 +235,13 @@ class MultiSpinGateway:
                             and pending < self.config.step_barrier)
             if pending == 0 or barrier_held:
                 self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(),
-                                           timeout=self.config.idle_wait_s)
-                except asyncio.TimeoutError:
-                    pass
+                with trace.span("gateway.idle", cat="gateway"):
+                    try:
+                        await asyncio.wait_for(
+                            self._wake.wait(),
+                            timeout=self.config.idle_wait_s)
+                    except asyncio.TimeoutError:
+                        pass
                 continue
             self._stepped = True
             try:
@@ -252,7 +252,8 @@ class MultiSpinGateway:
             except Exception as exc:
                 self._fail_rounds(exc)
                 return
-            self._dispatch_round(rec)
+            with trace.span("gateway.emit", cat="gateway"):
+                self._dispatch_round(rec)
             # yield so per-connection writers flush before the next round
             await asyncio.sleep(0)
 
@@ -487,6 +488,9 @@ class MultiSpinGateway:
                 b"Cache-Control: no-cache\r\n"
                 b"Connection: close\r\n\r\n")
             await writer.drain()
+            # the start of the request's gateway.queue span (cell.admit
+            # records it at the row bind)
+            req.queued_ns = time.perf_counter_ns()
             self._enqueue(("submit", req))
             st.push("queued", {"rid": req.rid, "tag": tag,
                                "trace_id": st.trace_id,

@@ -32,6 +32,9 @@ class Request:
     submit_time: float = 0.0
     first_token_time: float = 0.0  # clock at first committed token (TTFT)
     finish_time: float = 0.0
+    # host clock (time.perf_counter_ns) when the request was queued: the
+    # start of its ``gateway.queue`` span, which ends at its row bind
+    queued_ns: int = 0
 
 
 @dataclasses.dataclass

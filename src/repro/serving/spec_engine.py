@@ -316,6 +316,13 @@ class SpecEngine:
                 "engine with cache_kind='paged' to serve churn")
         n, M = prompts.shape
         Mb = self.prompt_bucket(M)
+        with _span("engine.add_streams", {"n": n, "M": M, "Mb": Mb}) as sp:
+            new_state, rows = self._add_streams(state, prompts, n, M, Mb)
+            sp.set(rows=rows)
+        return new_state, rows
+
+    def _add_streams(self, state: StreamState, prompts: jax.Array, n: int,
+                     M: int, Mb: int):
         B = state.pending.shape[0]
         rows = []
         for _ in range(n):
@@ -324,13 +331,14 @@ class SpecEngine:
             rows.append(row)
         allocated = []
         try:
-            for row in rows:
-                # transiently map the BUCKETED prefill extent; truncated back
-                # to the true prompt right after the prefill below
-                self.t_pages.alloc_stream(row, Mb - 1)
-                allocated.append((self.t_pages, row))
-                self.d_pages.alloc_stream(row, Mb - 1)
-                allocated.append((self.d_pages, row))
+            with _span("engine.page_alloc"):
+                for row in rows:
+                    # transiently map the BUCKETED prefill extent; truncated
+                    # back to the true prompt right after the prefill below
+                    self.t_pages.alloc_stream(row, Mb - 1)
+                    allocated.append((self.t_pages, row))
+                    self.d_pages.alloc_stream(row, Mb - 1)
+                    allocated.append((self.d_pages, row))
         except Exception:
             for mgr, row in allocated:
                 mgr.free_stream(row)
@@ -343,46 +351,53 @@ class SpecEngine:
         # Right-pad to the bucket with the last prompt token: padded K/V
         # lands at positions >= M-1, which every later window write covers
         # before attention can reach it (causal mask kj <= position).
-        if Mb > M:
-            pad = jnp.tile(prompts[:, -1:], (1, Mb - M))
-            padded = jnp.concatenate([prompts, pad], axis=1)
-        else:
-            padded = prompts
+        with _span("engine.prompt_pad"):
+            if Mb > M:
+                pad = jnp.tile(prompts[:, -1:], (1, Mb - M))
+                padded = jnp.concatenate([prompts, pad], axis=1)
+            else:
+                padded = prompts
         self.prefill_shapes.add((n, Mb))
         if self.on_prefill_trace is not None:
             self.on_prefill_trace((n, Mb))
-        t_view = dict(self.t_cache, pages=self.t_pages.device_table(rows))
-        d_view = dict(self.d_cache, pages=self.d_pages.device_table(rows))
-        _, t_view, _ = self.target.prefill(self.t_params, padded[:, :-1],
-                                           t_view)
-        _, d_view, _ = self.draft.prefill(self.d_params, padded[:, :-1],
-                                          d_view)
-        self.t_cache = strip_view(t_view)
-        self.d_cache = strip_view(d_view)
+        with _span("engine.prefill", {"model": "target"}):
+            t_view = dict(self.t_cache, pages=self.t_pages.device_table(rows))
+            _, t_view, _ = self.target.prefill(self.t_params,
+                                               padded[:, :-1], t_view)
+            self.t_cache = strip_view(t_view)
+        with _span("engine.prefill", {"model": "draft"}):
+            d_view = dict(self.d_cache, pages=self.d_pages.device_table(rows))
+            _, d_view, _ = self.draft.prefill(self.d_params,
+                                              padded[:, :-1], d_view)
+            self.d_cache = strip_view(d_view)
         if Mb > M:
             # hand the bucket-padding pages straight back to the pool
             for row in rows:
                 self.t_pages.truncate(row, M - 1)
                 self.d_pages.truncate(row, M - 1)
 
-        # splice the new rows into the batched state
-        n_grow = max(0, max(r + 1 for r in rows) - B) if rows else 0
-        pending = np.concatenate([np.asarray(state.pending),
-                                  np.zeros(n_grow, np.asarray(prompts).dtype)])
-        tpos = np.concatenate([np.asarray(state.target_pos),
-                               np.zeros(n_grow, np.int32)])
-        dpos = np.concatenate([np.asarray(state.draft_pos),
-                               np.zeros(n_grow, np.int32)])
-        committed = list(state.committed) + [None] * n_grow
-        pnp = np.asarray(prompts)
-        for i, row in enumerate(rows):
-            pending[row] = pnp[i, -1]
-            tpos[row] = dpos[row] = M - 1
-            committed[row] = list(pnp[i])
-        new_state = StreamState(pending=jnp.asarray(pending),
-                                target_pos=jnp.asarray(tpos, jnp.int32),
-                                draft_pos=jnp.asarray(dpos, jnp.int32),
-                                committed=committed)
+        # splice the new rows into the batched state: ONE fetch, which
+        # waits for the round still in flight and for these prefills
+        with _span("engine.state_splice"):
+            trace.incr("engine.admit_host_sync")
+            pending, tpos, dpos, pnp = jax.device_get(
+                (state.pending, state.target_pos, state.draft_pos, prompts))
+            n_grow = max(0, max(r + 1 for r in rows) - B) if rows else 0
+            pending = np.concatenate([np.asarray(pending),
+                                      np.zeros(n_grow, pnp.dtype)])
+            tpos = np.concatenate([np.asarray(tpos),
+                                   np.zeros(n_grow, np.int32)])
+            dpos = np.concatenate([np.asarray(dpos),
+                                   np.zeros(n_grow, np.int32)])
+            committed = list(state.committed) + [None] * n_grow
+            for i, row in enumerate(rows):
+                pending[row] = pnp[i, -1]
+                tpos[row] = dpos[row] = M - 1
+                committed[row] = list(pnp[i])
+            new_state = StreamState(pending=jnp.asarray(pending),
+                                    target_pos=jnp.asarray(tpos, jnp.int32),
+                                    draft_pos=jnp.asarray(dpos, jnp.int32),
+                                    committed=committed)
         return new_state, rows
 
     def retire_stream(self, row: int) -> None:
@@ -561,7 +576,7 @@ class SpecEngine:
             tpos = jnp.where(live, jnp.take(state.target_pos, idx), zi)
 
         # --- step 2: distributed drafting (SLM) ---
-        with _span("engine.draft", {"B": n, "L": L}) as sp:
+        with _span("engine.draft", {"B": n, "L": L}):
             if self._steps.draft is not None:
                 # compiled path: ONE jitted call per (n, L) bucket; the
                 # draft KV pytree is passed (and in jit+donate mode donated)
@@ -580,7 +595,6 @@ class SpecEngine:
                                             d_cache, pending, dpos, L, key,
                                             vhat=vhat)
                 self.d_cache = strip_view(draft_res.cache)
-            sp.attach(draft_res.tokens)
         return RoundTicket(rows=None if full else row_list, lengths=lengths,
                            L=L, freeze=frz, pending=pending, target_pos=tpos,
                            draft=draft_res)
@@ -603,13 +617,12 @@ class SpecEngine:
             # one jitted call (per (n, L) bucket); the target KV pytree is
             # donated in jit+donate mode, the page table rides un-donated
             with _span("engine.target_pass",
-                       {"B": n, "W": ticket.L + 1}) as sp:
+                       {"B": n, "W": ticket.L + 1}):
                 res, t_kv = self._steps.verify(
                     self.t_params, self.t_cache, t_pt, ticket.pending,
                     draft_res.tokens, draft_res.probs, draft_res.q_idx,
                     draft_res.q_val, ticket.target_pos, draft_len, key)
                 self.t_cache = t_kv
-                sp.attach(res.accept_counts)
             ticket.res = res
             return ticket
 
@@ -618,7 +631,7 @@ class SpecEngine:
         # --- step 4: batched verification (LLM) ---
         window = jnp.concatenate([ticket.pending[:, None], draft_res.tokens],
                                  axis=1)                       # (n, L+1)
-        with _span("engine.target_pass", {"B": n, "W": ticket.L + 1}) as sp:
+        with _span("engine.target_pass", {"B": n, "W": ticket.L + 1}):
             if needs_state_rollback(self.target_cfg):
                 logits, t_cache, snaps = self.target.forward_window(
                     self.t_params, window, t_cache, ticket.target_pos,
@@ -627,13 +640,11 @@ class SpecEngine:
                 logits, t_cache = self.target.forward_window(
                     self.t_params, window, t_cache, ticket.target_pos)
                 snaps = None
-            sp.attach(logits)
 
-        with _span("engine.verify_tokens", {"B": n, "L": ticket.L}) as sp:
+        with _span("engine.verify_tokens", {"B": n, "L": ticket.L}):
             res = verify_drafts(key, draft_res.tokens, draft_res.probs,
                                 logits, q_idx=draft_res.q_idx,
                                 q_val=draft_res.q_val, draft_len=draft_len)
-            sp.attach(res.accept_counts)
 
         # target cache: row i processed [pending, d_1..d_n]; snapshot index
         # n (0-based: snapshot t is the state after feeding window[:, :t+1])
@@ -828,7 +839,7 @@ class SpecEngine:
 
         # --- step 2: J drafting runs per stream (SLM) ---
         scatter = self.tree_commit == "scatter" and J > 1
-        with _span("engine.draft_forest", {"B": B, "L": L, "J": J}) as sp:
+        with _span("engine.draft_forest", {"B": B, "L": L, "J": J}):
             if steps is not None:
                 forest = steps.forest(self.d_params, self.d_cache, d_pt,
                                       state.pending, state.draft_pos,
@@ -842,7 +853,6 @@ class SpecEngine:
                     state.pending, state.draft_pos, L, J, k_draft,
                     vhat=vhat, keep_windows=scatter)
                 self.d_cache = strip_view(forest.cache)
-            sp.attach(forest.tokens)
 
         # --- pack into the prefix-deduplicated tree (host-side) ---
         with _span("engine.tree_build", {"B": B, "L": L, "J": J}):
@@ -868,23 +878,20 @@ class SpecEngine:
         # --- step 4: ONE ancestor-masked target pass over the whole tree ---
         if steps is not None:
             with _span("engine.target_pass",
-                       {"B": B, "W": W + 1, "J": J}) as sp:
+                       {"B": B, "W": W + 1, "J": J}):
                 res, self.t_cache = steps.tree_verify(
                     self.t_params, self.t_cache, t_pt, window, wmask, wdepth,
                     state.target_pos, k_verify, *tree_args, *q_args)
-                sp.attach(res.accept_counts)
         else:
             with _span("engine.target_pass",
-                       {"B": B, "W": W + 1, "J": J}) as sp:
+                       {"B": B, "W": W + 1, "J": J}):
                 logits, t_cache = self.target.forward_window(
                     self.t_params, window, view(self.t_cache, t_pt),
                     state.target_pos, window_mask=wmask, window_depth=wdepth)
                 self.t_cache = strip_view(t_cache)
-                sp.attach(logits)
             with _span("engine.verify_tokens",
-                       {"B": B, "L": L, "J": J}) as sp:
+                       {"B": B, "L": L, "J": J}):
                 res = verify_tree(k_verify, *tree_args, logits, *q_args)
-                sp.attach(res.accept_counts)
 
         # --- step 5a: land the accepted path's K/V (a J=1 chain already IS
         # the sequential window: nothing to move)
@@ -895,7 +902,7 @@ class SpecEngine:
             # exactly its root-to-node path), so move the winning branch's
             # rows from their tree-window slots into the committed slots —
             # no repair forward, and no host sync on accept_counts
-            with _span("engine.kv_commit", {"B": B, "L": L, "J": J}) as sp:
+            with _span("engine.kv_commit", {"B": B, "L": L, "J": J}):
                 if steps is not None:
                     self.t_cache, self.d_cache = steps.tree_commit(
                         self.t_cache, t_pt, self.d_cache, d_pt,
@@ -910,7 +917,6 @@ class SpecEngine:
                         state.draft_pos)
                     self.t_cache = strip_view(t_cache)
                     self.d_cache = strip_view(d_cache)
-                sp.attach(self.t_cache["k"])
         elif J > 1:
             # repair forward (kept as the reference path, and for targets
             # whose window pass cannot donate K/V): one plain causal window
@@ -919,7 +925,7 @@ class SpecEngine:
             repair = jnp.concatenate(
                 [state.pending[:, None], res.output_tokens[:, :n_max]],
                 axis=1)                                        # (B, n_max+1)
-            with _span("engine.cache_repair", {"B": B, "n": n_max + 1}) as sp:
+            with _span("engine.cache_repair", {"B": B, "n": n_max + 1}):
                 _, t_cache = self.target.forward_window(
                     self.t_params, repair, view(self.t_cache, t_pt),
                     state.target_pos)
@@ -928,7 +934,6 @@ class SpecEngine:
                     state.draft_pos)
                 self.t_cache = strip_view(t_cache)
                 self.d_cache = strip_view(d_cache)
-                sp.attach(self.t_cache)
 
         # --- step 5b: commit + rollback (identical to the sequential round)
         adv = jnp.where(frz, 0, 1 + res.accept_counts)

@@ -1,6 +1,7 @@
-"""Observability subsystem: span tracer semantics, kernel dispatch spans,
-cell/engine span nesting, the gateway ``/v1/trace`` endpoint, and the BENCH
-regression gate.
+"""Observability subsystem: span tracer semantics, spans on the profiler's
+clock, recorded intervals and JAX's compile events, kernel dispatch spans,
+cell/engine span nesting (rounds and admission), the gateway's queue and
+``/v1/trace`` endpoint, and the BENCH regression gate.
 
 Async tests run through ``asyncio.run`` inside sync test functions (no
 pytest-asyncio dependency).
@@ -50,7 +51,6 @@ def test_disabled_tracing_returns_the_null_singleton():
     assert trace.span("other") is sp          # identity, not equality
     with sp as inner:                          # usable as a context manager
         inner.set(a=1)
-        inner.attach(object())
 
     import jax.numpy as jnp
 
@@ -139,27 +139,6 @@ def test_ring_is_bounded_and_counts_drops():
     assert tracer.snapshot() == [] and tracer.dropped == 0
 
 
-@pytest.mark.parametrize("device_sync", [False, True])
-def test_recorded_spans_hold_no_attached_value(device_sync):
-    """A span's attached value is only blocked on at exit: the ring must not
-    keep it alive, or every traced round would pin its device buffers."""
-    import gc
-    import weakref
-
-    import jax.numpy as jnp
-
-    tracer = trace.Tracer(device_sync=device_sync)
-    with trace.tracing(tracer):
-        value = jnp.ones((4, 4))
-        ref = weakref.ref(value)
-        with trace.span("s") as sp:
-            sp.attach(value)
-        del value
-        gc.collect()
-    assert ref() is None
-    assert [sp.name for sp in tracer.snapshot()] == ["s"]
-
-
 def test_totals_aggregate_matches_snapshot():
     with trace.tracing() as tr:
         for _ in range(3):
@@ -172,6 +151,142 @@ def test_totals_aggregate_matches_snapshot():
     assert totals["a"]["count"] == 3 and totals["b"]["count"] == 2
     want = sum(sp.dur_ns for sp in tr.snapshot() if sp.name == "a") * 1e-9
     assert totals["a"]["seconds"] == pytest.approx(want)
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock, recorded intervals, JAX's compile events
+# ---------------------------------------------------------------------------
+
+def test_profiler_spans_open_and_close_annotations_in_nesting_order(
+        monkeypatch):
+    import jax
+
+    events = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("open", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    with trace.tracing(trace.Tracer(profiler=True)) as tr:
+        with trace.span("outer"):
+            with trace.span("inner"):
+                pass
+        with trace.span("after"):
+            pass
+    assert events == [("open", "obs:outer"), ("open", "obs:inner"),
+                      ("close", "obs:inner"), ("close", "obs:outer"),
+                      ("open", "obs:after"), ("close", "obs:after")]
+    assert [sp.name for sp in tr.snapshot()] == ["inner", "outer", "after"]
+    # a recorded span holds no annotation once closed
+    assert all(sp._annotation is None for sp in tr.snapshot())
+
+
+def test_profiler_spans_reach_a_real_profile(tmp_path):
+    """Under an active profiler session the obs: spans land on the trace's
+    host plane, as devtrace reads them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with trace.tracing(trace.Tracer(profiler=True)):
+        jax.profiler.start_trace(str(tmp_path))
+        with trace.span("outer"):
+            with trace.span("inner"):
+                pass
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    names = {e.name for p in ProfileData.from_file(str(path)).planes
+             if p.name.startswith("/host:") for ln in p.lines
+             for e in ln.events}
+    assert {"obs:outer", "obs:inner"} <= names
+
+
+def test_record_exports_the_given_start_and_duration():
+    tracer = trace.Tracer()
+    t0 = tracer.t0_ns + 5_000_000            # 5 ms after tracer start
+    with trace.tracing(tracer):
+        with trace.span("open") as outer:
+            inside = tracer.record("late", outer.t0_ns + 1000,
+                                   outer.t0_ns + 3000, cat="q",
+                                   args={"rid": 7})
+            before = tracer.record("early", outer.t0_ns - 10,
+                                   outer.t0_ns + 10)
+        sp = tracer.record("queued", t0, t0 + 2_500_000, args={"rid": 3})
+    assert (sp.t0_ns, sp.dur_ns, sp.parent_sid) == (t0, 2_500_000, -1)
+    # filed under the innermost open span that began no later than it
+    assert inside.parent_sid == outer.sid and before.parent_sid == -1
+    ev = {e["name"]: e for e in tracer.export_chrome_trace()["traceEvents"]
+          if e["ph"] == "X"}
+    assert ev["queued"]["ts"] == pytest.approx(5000.0)
+    assert ev["queued"]["dur"] == pytest.approx(2500.0)
+    assert ev["queued"]["args"]["rid"] == 3
+    assert ev["late"]["cat"] == "q" and ev["late"]["dur"] == pytest.approx(2.0)
+
+
+def test_jax_compile_events_land_as_intervals_only_while_installed():
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_obs_fn(x):
+        return jnp.cos(x) * 3.0 - 1.0
+
+    with trace.tracing() as tr:
+        with trace.span("caller") as caller:
+            jax.jit(fresh_obs_fn)(jnp.ones(5)).block_until_ready()
+    spans = {(sp.name, (sp.args or {}).get("fun_name")): sp
+             for sp in tr.snapshot() if sp.cat == "jax"}
+    assert ("jax.trace", "fresh_obs_fn") in spans
+    assert ("jax.lower", "jit(fresh_obs_fn)") in spans
+    assert ("jax.compile", "jit(fresh_obs_fn)") in spans
+    for sp in spans.values():
+        assert sp.parent_sid == caller.sid
+        assert caller.t0_ns <= sp.t0_ns
+        assert sp.t0_ns + sp.dur_ns <= caller.t0_ns + caller.dur_ns + 1_000_000
+    assert tr.counters()["jax.compile"] >= 1
+
+    # no tracer installed: the listener forwards nothing, anywhere
+    idle = trace.Tracer()
+    assert trace.active() is None
+
+    def other_obs_fn(x):
+        return jnp.tan(x) + 2.0
+
+    jax.jit(other_obs_fn)(jnp.ones(5)).block_until_ready()
+    assert idle.snapshot() == [] and idle.counters() == {}
+    assert not any((sp.args or {}).get("fun_name", "").endswith(
+        "other_obs_fn") for sp in tr.snapshot())
+
+
+def test_cache_hits_are_counted():
+    from jax import monitoring
+
+    with trace.tracing() as tr:
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event("/jax/some/other_event")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert tr.counters() == {"jax.cache_hit": 1}
+
+
+def test_obs_stays_importable_without_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; from repro.obs import trace; "
+            "t = trace.install(trace.Tracer()); "
+            "[trace.span('s').__enter__().__exit__()]; "
+            "t.record('r', 0, 1); trace.uninstall(); "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -335,6 +450,31 @@ def test_gateway_reuses_an_already_installed_tracer():
     assert trace.active() is None
 
 
+def test_gateway_request_records_its_queue_wait():
+    """One gateway request: one gateway.queue interval carrying its rid,
+    from the gateway's enqueue to its bind in cell.admit, plus the loop's
+    emit and idle spans."""
+    async def run(cell):
+        gw, cli = await _start(cell, trace_spans=True)
+        res = await cli.generate(prompt_len=8, max_new_tokens=8,
+                                 alpha=0.8, T_S=0.009)
+        spans = gw.tracer.snapshot()
+        await gw.stop()
+        return res, spans
+
+    res, spans = asyncio.run(run(_cell(max_batch=2)))
+    queue = [sp for sp in spans if sp.name == "gateway.queue"]
+    assert len(queue) == 1
+    assert queue[0].args == {"rid": res.rid}
+    admits = [sp for sp in spans if sp.name == "cell.admit"
+              and sp.args["n"] == 1]
+    assert len(admits) == 1
+    end = queue[0].t0_ns + queue[0].dur_ns
+    assert admits[0].t0_ns <= end <= admits[0].t0_ns + admits[0].dur_ns
+    names = {sp.name for sp in spans}
+    assert {"gateway.emit", "gateway.idle", "cell.step"} <= names
+
+
 # ---------------------------------------------------------------------------
 # the full nesting chain on a REAL engine backend
 # ---------------------------------------------------------------------------
@@ -387,6 +527,62 @@ def test_engine_backend_nests_cell_engine_and_kernel_spans():
     # and at least one kernel dispatch span has the FULL chain above it
     chains = [ancestors(sp) for sp in spans if sp.name.startswith("ops.")]
     assert any("engine.verify" in c and "cell.step" in c for c in chains)
+
+
+def test_engine_admission_nests_its_spans_under_cell_admit():
+    """An admission through a paged engine: cell.admit > engine.add_streams
+    > {page_alloc, prompt_pad, two prefills, state_splice}, on one thread,
+    with one splice fetch counted and each request's queue wait recorded
+    from its submit to its bind."""
+    import jax
+
+    from repro.api import EngineBackend, SpecEngine
+    from repro.configs import get_config
+
+    tcfg = get_config("qwen2.5-3b").smoke()
+    dcfg = tcfg.replace(num_layers=1, d_model=32, num_heads=2,
+                        num_kv_heads=1, head_dim=16, d_ff=64,
+                        name="draft-smoke")
+    eng = SpecEngine(tcfg, dcfg, max_len=64, cache_kind="paged")
+    eng.init_params(jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0,
+                                 tcfg.vocab_size)
+    backend = EngineBackend(eng, eng.start(prompts))
+    cell = MultiSpinCell(CellConfig(scheme="fixed", L_fixed=3, max_batch=2,
+                                    seed=0), backend=backend)
+    with trace.tracing() as tr:
+        for i in range(2):      # the first takes the started row
+            cell.submit(Request(rid=i, prompt_len=12, max_new_tokens=8,
+                                alpha=0.8, T_S=0.009))
+        assert len(cell.admit()) == 2
+
+    spans = tr.snapshot()
+    by_sid = {sp.sid: sp for sp in spans}
+    (admit,) = [sp for sp in spans if sp.name == "cell.admit"]
+    (add,) = [sp for sp in spans if sp.name == "engine.add_streams"]
+    assert admit.args["n"] == 2
+    assert (add.args["n"], add.args["M"], add.args["Mb"]) == (1, 12, 16)
+    assert add.args["rows"] == [1]
+    assert by_sid[add.parent_sid] is admit and admit.parent_sid == -1
+    kids = sorted(sp.name for sp in spans if sp.parent_sid == add.sid)
+    assert kids == ["engine.page_alloc", "engine.prefill", "engine.prefill",
+                    "engine.prompt_pad", "engine.state_splice"]
+    assert sorted(sp.args["model"] for sp in spans
+                  if sp.name == "engine.prefill") == ["draft", "target"]
+    for sp in spans:
+        if sp.parent_sid == add.sid:
+            assert sp.tid == add.tid
+            assert add.t0_ns <= sp.t0_ns
+            assert sp.t0_ns + sp.dur_ns <= add.t0_ns + add.dur_ns
+    assert add.tid == admit.tid
+    assert tr.counters()["engine.admit_host_sync"] == 1
+    queue = sorted((sp.args["rid"], sp) for sp in spans
+                   if sp.name == "gateway.queue")
+    assert [rid for rid, _ in queue] == [0, 1]
+    for _, sp in queue:
+        assert sp.t0_ns < admit.t0_ns + admit.dur_ns and sp.dur_ns > 0
+    # the second request waited through the first one's bind
+    assert queue[1][1].dur_ns >= queue[0][1].dur_ns
 
 
 # ---------------------------------------------------------------------------
