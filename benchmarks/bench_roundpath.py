@@ -94,7 +94,8 @@ def run_mode(mode: str, seed: int, warm_rounds: int, rounds: int) -> dict:
         "compile_s": compile_s,
         "host_syncs_per_round": (eng.host_syncs - h0) / rounds,
         "retraces": len(retraced),
-        "step_shapes": len(eng.step_shapes),
+        # the round steps' traced shapes; start()'s prefill is not a round
+        "step_shapes": sum(k[0] != "prefill" for k in eng.step_shapes),
         "committed": [list(map(int, c)) for c in st.committed],
     }
 

@@ -18,6 +18,16 @@ and the token-tree round's (``multidraft``, J > 1) three steps:
   * ``tree_commit_step`` — scatter-commit of the winning branch's K/V;
                            donates both KV caches
 
+and the paged admission prefill:
+
+  * ``prefill_step`` — both models' prefill of the admitted prompts at one
+                       prompt bucket; donates both KV caches (argnum 1)
+                       and returns only the caches, so the final norm and
+                       the vocabulary projection over every prompt
+                       position are never computed.  The eager prefill
+                       remains for ``eager`` mode, the contiguous
+                       ``start()`` and ``serving/engine.py``.
+
 Donation invariants (docs/architecture.md "compilation & memory model"):
 
   * a donated buffer is DEAD after the call — the engine adopts the
@@ -29,10 +39,11 @@ Donation invariants (docs/architecture.md "compilation & memory model"):
     so a stale page table can never ride along inside an adopted cache.
 
 Shapes are keyed at the same pow2 (batch, length) buckets the continuous
-engine's ``BatchAssembler`` emits, which bounds retraces; the ``record``
-hook fires only at TRACE time (python inside a jitted body), mirroring the
-``prefill_shapes`` / ``BatchAssembler.shapes`` accounting idiom, so tests
-can assert the retrace count.
+engine's ``BatchAssembler`` emits, and the prefill at the engine's pow2
+prompt buckets, which bounds retraces; the ``record`` hook fires only at
+TRACE time (python inside a jitted body), mirroring the
+``BatchAssembler.shapes`` accounting idiom, so tests can assert the
+retrace count.
 """
 
 from __future__ import annotations
@@ -150,10 +161,11 @@ def tree_scatter_commit(t_cache, d_cache, windows, paths, winner,
 class RoundSteps:
     """The compiled (or eager) step callables for one engine.
 
-    ``draft`` / ``verify`` and the tree steps ``forest`` / ``tree_verify``
-    / ``tree_commit`` are None in eager mode — the engine keeps its
-    op-by-op dispatch path; ``commit`` is always callable (the eager path
-    shares the same device-side commit math, just unjitted).
+    ``draft`` / ``verify``, the tree steps ``forest`` / ``tree_verify``
+    / ``tree_commit`` and the admission ``prefill`` are None in eager mode
+    — the engine keeps its op-by-op dispatch path; ``commit`` is always
+    callable (the eager path shares the same device-side commit math, just
+    unjitted).
     """
 
     mode: str
@@ -163,6 +175,7 @@ class RoundSteps:
     forest: Callable | None = None
     tree_verify: Callable | None = None
     tree_commit: Callable | None = None
+    prefill: Callable | None = None
 
 
 def build_round_steps(target_model, draft_model, *, mode: str,
@@ -238,6 +251,17 @@ def build_round_steps(target_model, draft_model, *, mode: str,
             tpos, dpos)
         return strip_view(t_cache), strip_view(d_cache)
 
+    def prefill_step(params, kv, pages, tokens):
+        # params / kv / pages are (target, draft) pairs; tokens (n, Mb) is
+        # the bucket-padded prompt batch, whose last token stays pending
+        _record("prefill", tokens.shape[0], tokens.shape[1])
+        caches = []
+        for model, p, c, pt in zip((target_model, draft_model), params, kv,
+                                   pages):
+            _, cache, _ = model.prefill(p, tokens[:, :-1], dict(c, pages=pt))
+            caches.append(strip_view(cache))
+        return tuple(caches)
+
     if mode == "eager":
         return RoundSteps(mode=mode, draft=None, verify=None,
                           commit=commit_step)
@@ -255,4 +279,6 @@ def build_round_steps(target_model, draft_model, *, mode: str,
                             donate_argnums=(1,) if donate else ()),
         tree_commit=jax.jit(tree_commit_step,
                             donate_argnums=(0, 2) if donate else ()),
+        prefill=jax.jit(prefill_step,
+                        donate_argnums=(1,) if donate else ()),
     )

@@ -173,8 +173,9 @@ class SpecEngine:
         self.d_pages: PagedKVCache | None = None
         self._free_rows: list[int] = []
         self._retired: set[int] = set()
-        # compile accounting: every paged-prefill batch shape actually traced
-        # (one XLA trace per distinct shape); tests hook ``on_prefill_trace``
+        # compile accounting: every paged-prefill (n, Mb) shape traced —
+        # at trace time under jit, on every call in eager mode (where each
+        # call traces the layer scan anew); tests hook ``on_prefill_trace``
         self.prefill_shapes: set[tuple[int, int]] = set()
         self.on_prefill_trace = None
         # compiled round path: jitted draft/verify/commit step functions
@@ -200,6 +201,17 @@ class SpecEngine:
         self.step_shapes.add(shape)
         if self.on_step_trace is not None:
             self.on_step_trace(shape)
+        if shape[0] == "prefill":
+            self._record_prefill(shape[1], shape[2])
+
+    def _record_prefill(self, n: int, Mb: int) -> None:
+        """One prefill trace at ``(n, Mb)``; admissions
+        (``engine.admit_host_sync``) minus ``engine.prefill_trace`` is the
+        number served without one."""
+        self.prefill_shapes.add((n, Mb))
+        if self.on_prefill_trace is not None:
+            self.on_prefill_trace((n, Mb))
+        trace.incr("engine.prefill_trace")
 
     def _host_fetch(self, value):
         """Blocking device->host fetch.  The ONE per-round call site is the
@@ -357,19 +369,29 @@ class SpecEngine:
                 padded = jnp.concatenate([prompts, pad], axis=1)
             else:
                 padded = prompts
-        self.prefill_shapes.add((n, Mb))
-        if self.on_prefill_trace is not None:
-            self.on_prefill_trace((n, Mb))
-        with _span("engine.prefill", {"model": "target"}):
-            t_view = dict(self.t_cache, pages=self.t_pages.device_table(rows))
-            _, t_view, _ = self.target.prefill(self.t_params,
-                                               padded[:, :-1], t_view)
-            self.t_cache = strip_view(t_view)
-        with _span("engine.prefill", {"model": "draft"}):
-            d_view = dict(self.d_cache, pages=self.d_pages.device_table(rows))
-            _, d_view, _ = self.draft.prefill(self.d_params,
-                                              padded[:, :-1], d_view)
-            self.d_cache = strip_view(d_view)
+        if self._steps.prefill is not None:
+            # one compiled program per (n, Mb) bucket; in jit+donate the
+            # pools are donated and the returned ones adopted
+            with _span("engine.prefill"):
+                self.t_cache, self.d_cache = self._steps.prefill(
+                    (self.t_params, self.d_params),
+                    (self.t_cache, self.d_cache),
+                    (self.t_pages.device_table(rows),
+                     self.d_pages.device_table(rows)), padded)
+        else:
+            self._record_prefill(n, Mb)
+            with _span("engine.prefill", {"model": "target"}):
+                t_view = dict(self.t_cache,
+                              pages=self.t_pages.device_table(rows))
+                _, t_view, _ = self.target.prefill(self.t_params,
+                                                   padded[:, :-1], t_view)
+                self.t_cache = strip_view(t_view)
+            with _span("engine.prefill", {"model": "draft"}):
+                d_view = dict(self.d_cache,
+                              pages=self.d_pages.device_table(rows))
+                _, d_view, _ = self.draft.prefill(self.d_params,
+                                                  padded[:, :-1], d_view)
+                self.d_cache = strip_view(d_view)
         if Mb > M:
             # hand the bucket-padding pages straight back to the pool
             for row in rows:

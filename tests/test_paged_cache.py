@@ -436,3 +436,105 @@ def test_bucketed_prefill_numerics_match_exact_prefill():
     full, _ = eng.target.apply(eng.t_params, seq)
     np.testing.assert_allclose(np.asarray(inc[b, 0]), np.asarray(full[0, -1]),
                                rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# Compiled admission prefill (one jitted step per prompt bucket)
+# ---------------------------------------------------------------------------
+
+_ADMIT_LENGTHS = (11, 20, 9)            # buckets 16, 32, 16
+_ADMITTED = {}
+
+
+def _admit_and_serve(mode, J):
+    """start() a paged engine, admit prompts at bucketed lengths, run a few
+    rounds of draft width ``J``; returns (engine, state)."""
+    key = (mode, J)
+    if key not in _ADMITTED:
+        tcfg, dcfg = _engine_pair()
+        eng = SpecEngine(tcfg, dcfg, max_len=96, cache_kind="paged",
+                         num_pages=240, compile_mode=mode)
+        eng.init_params(jax.random.PRNGKey(0))
+        state = eng.start(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
+                                             0, tcfg.vocab_size))
+        for i, M in enumerate(_ADMIT_LENGTHS):
+            state, _ = eng.add_streams(state, jax.random.randint(
+                jax.random.PRNGKey(100 + i), (1, M), 0, tcfg.vocab_size))
+        B = state.pending.shape[0]
+        for r in range(2):
+            state, _, _ = eng.spin_round(state, np.full(B, 3),
+                                         jax.random.PRNGKey(10 + r),
+                                         draft_width=J)
+        _ADMITTED[key] = (eng, state)
+    return _ADMITTED[key]
+
+
+def _live_kv(cache, mgr, row):
+    """The K/V of ``row``'s mapped positions, gathered through its pages."""
+    pos = np.arange(mgr.length(row))
+    pages = np.asarray(mgr.page_table([row]))[0][pos // mgr.page_size]
+    return {k: np.asarray(v)[:, pages, pos % mgr.page_size]
+            for k, v in cache.items()}
+
+
+@pytest.mark.parametrize("J", [1, 2])
+@pytest.mark.parametrize("mode", ["jit", "jit+donate"])
+def test_compiled_prefill_matches_eager(mode, J):
+    """Admissions through the jitted prefill step commit the same tokens as
+    the eager prefill, leave the same K/V in every live slot of both pools,
+    and keep the allocators consistent."""
+    eng, state = _admit_and_serve(mode, J)
+    ref, ref_state = _admit_and_serve("eager", J)
+    assert ({M for _, M in eng.prefill_shapes}
+            == {M for _, M in ref.prefill_shapes} == {16, 32})
+    assert ([list(map(int, c)) for c in state.committed]
+            == [list(map(int, c)) for c in ref_state.committed])
+    for pool, ref_pool, mgr, ref_mgr in (
+            (eng.t_cache, ref.t_cache, eng.t_pages, ref.t_pages),
+            (eng.d_cache, ref.d_cache, eng.d_pages, ref.d_pages)):
+        mgr.check_invariants()
+        assert mgr.streams() == ref_mgr.streams()
+        for row in mgr.streams():
+            assert mgr.length(row) == ref_mgr.length(row)
+            got, want = _live_kv(pool, mgr, row), _live_kv(ref_pool, ref_mgr,
+                                                           row)
+            for leaf in want:
+                np.testing.assert_array_equal(got[leaf], want[leaf])
+
+
+@pytest.mark.parametrize("mode", ["jit", "jit+donate"])
+def test_compiled_prefill_traces_once_per_bucket(mode):
+    """A re-admission at a warmed prompt bucket runs the compiled prefill
+    with no retrace (no new ``("prefill", n, Mb)`` step shape, no
+    ``engine.prefill_trace``); under ``jit+donate`` it donates the pools
+    and the engine adopts the step's live outputs."""
+    from repro.obs import trace
+
+    tcfg, dcfg = _engine_pair()
+    eng = SpecEngine(tcfg, dcfg, max_len=96, cache_kind="paged",
+                     num_pages=240, compile_mode=mode)
+    eng.init_params(jax.random.PRNGKey(0))
+    state = eng.start(jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0,
+                                         tcfg.vocab_size))
+    with trace.tracing() as tr:
+        state, _ = eng.add_streams(state, jax.random.randint(
+            jax.random.PRNGKey(2), (1, 11), 0, tcfg.vocab_size))
+    assert tr.counters()["engine.prefill_trace"] == 1
+    assert ("prefill", 1, 16) in eng.step_shapes
+    assert eng.prefill_shapes == {(1, 8), (1, 16)}
+    old = jax.tree.leaves((eng.t_cache, eng.d_cache))
+    retraced, shapes = [], set(eng.step_shapes)
+    eng.on_step_trace = eng.on_prefill_trace = retraced.append
+    with trace.tracing() as tr:
+        state, rows = eng.add_streams(state, jax.random.randint(
+            jax.random.PRNGKey(3), (1, 13), 0, tcfg.vocab_size))
+    assert retraced == [] and eng.step_shapes == shapes
+    assert tr.counters()["engine.admit_host_sync"] == 1
+    assert "engine.prefill_trace" not in tr.counters()
+    new = jax.tree.leaves((eng.t_cache, eng.d_cache))
+    assert not any(x.is_deleted() for x in new)
+    deleted = [x.is_deleted() for x in old]
+    assert all(deleted) if mode == "jit+donate" else not any(deleted)
+    assert state.committed[rows[0]][-1] == int(state.pending[rows[0]])
+    eng.t_pages.check_invariants()
+    eng.d_pages.check_invariants()

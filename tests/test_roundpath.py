@@ -100,7 +100,8 @@ def test_tree_rounds_trace_once_per_bucket():
     st, _, _ = eng.spin_round(st, np.full(B, L), base, vhat=VHAT,
                               draft_width=2)
     assert {k for k in eng.step_shapes if k[0] != "commit"} == {
-        ("forest", B, L), ("tree_verify", B, L), ("tree_commit", B, L)}
+        ("prefill", B, 16), ("forest", B, L), ("tree_verify", B, L),
+        ("tree_commit", B, L)}
     retraced = []
     eng.on_step_trace = retraced.append
     for r in range(2):
@@ -141,14 +142,15 @@ def test_one_host_sync_per_linear_round(mode):
 
 
 def test_warmup_bounds_retraces():
-    """``warmup(buckets)`` pre-traces draft/verify/commit at each bucket;
-    real rounds at those shapes must not retrace (shape-keyed, counted by
-    the trace-time ``on_step_trace`` hook)."""
+    """``warmup(buckets)`` pre-traces draft/verify/commit at each bucket
+    (``start()`` has traced the prefill at its prompt bucket); real rounds
+    at those shapes must not retrace (shape-keyed, counted by the
+    trace-time ``on_step_trace`` hook)."""
     eng, prompts = _engine("jit+donate")
     st = eng.start(prompts)
     st, _ = eng.warmup(st, [(B, L)], vhat=VHAT)
-    assert eng.step_shapes == {("draft", B, L), ("verify", B, L),
-                               ("commit", B, L)}
+    assert eng.step_shapes == {("prefill", B, 16), ("draft", B, L),
+                               ("verify", B, L), ("commit", B, L)}
     retraced = []
     eng.on_step_trace = retraced.append
     base = jax.random.PRNGKey(7)
