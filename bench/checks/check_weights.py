@@ -2,7 +2,7 @@
 
     JAX_PLATFORMS=cpu python3 bench/checks/check_weights.py
 
-Builds a smoke-size target and draft with ``weights.make_params`` (the
+Builds a smoke-size Qwen2 target and draft with ``archs/qwen2.py`` (the
 construction of the configuration file, at small widths), runs the float32
 reference over sequences that stay in one cluster (as served text does),
 and measures at every position the acceptance per drafted token, the
@@ -25,17 +25,11 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import weights  # noqa: E402
+from archs import qwen2 as qwen2_arch  # noqa: E402
 from references import qwen2  # noqa: E402
 
 TOL = 0.05          # per-task mean acceptance against its stated rate
 N_SEQ, T = 12, 48   # sequences per task, tokens per sequence
-
-
-def smoke(layers, d, heads, ff, V=2048):
-    return {"hidden_size": d, "intermediate_size": ff,
-            "num_attention_heads": heads, "num_hidden_layers": layers,
-            "num_key_value_heads": 2, "vocab_size": V, "rope_theta": 1e6,
-            "tie_word_embeddings": True, "attention_bias": True}
 
 
 def main():
@@ -43,11 +37,12 @@ def main():
                         .read_text())
     acc = dict(config["acceptance"], code_dims=32)
     vhat = config["serving"]["vhat"]
-    tgt, drf = smoke(4, 256, 4, 512), smoke(2, 128, 4, 256)
+    tgt = qwen2_arch.smoke(4, 256, 4, 512, vocab=2048)
+    drf = qwen2_arch.smoke(2, 128, 4, 256, vocab=2048)
     V, m = tgt["vocab_size"], acc["cluster_size"]
     kt, kd = weights.pair_keys(20261017)
-    pt = weights.make_params(tgt, acc, kt, "float32")
-    pd = weights.make_params(drf, acc, kd, "float32")
+    pt = qwen2_arch.make_params(tgt, acc, kt, "float32")
+    pd = qwen2_arch.make_params(drf, acc, kd, "float32")
     n_tasks = len(acc["task_sigma"])
     per = (V // m) // n_tasks * m
     rng = np.random.default_rng(0)

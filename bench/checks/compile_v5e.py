@@ -32,8 +32,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-import servecell  # noqa: E402
-import weights  # noqa: E402
+import arch  # noqa: E402
 
 GiB = 2 ** 30
 PREFILL_BUCKETS = (64, 128, 256, 512)   # 512 is refused (PERF.md)
@@ -54,8 +53,9 @@ def main(names):
         srv = config["serving"]
         from repro.models import build_model
         from repro.serving.compiled import build_round_steps
-        tcfg = servecell.model_config(config["target"], "t", srv["dtype"])
-        dcfg = servecell.model_config(config["draft"], "d", srv["dtype"])
+        t_arch, d_arch = arch.of(config["target"]), arch.of(config["draft"])
+        tcfg = t_arch.model_config(config["target"], "t", srv["dtype"])
+        dcfg = d_arch.model_config(config["draft"], "d", srv["dtype"])
         target, draft = build_model(tcfg), build_model(dcfg)
         steps = build_round_steps(target, draft, mode=srv["compile"])
         B, ps, max_len, vhat = (srv["max_batch"], srv["page_size"],
@@ -65,9 +65,9 @@ def main(names):
         J = srv.get("draft_width", 1)
         acc = config["acceptance"]
         key = jax.random.PRNGKey(0)
-        tp = put(jax.eval_shape(lambda: weights.make_params(
+        tp = put(jax.eval_shape(lambda: t_arch.make_params(
             config["target"], acc, key, srv["dtype"])))
-        dp = put(jax.eval_shape(lambda: weights.make_params(
+        dp = put(jax.eval_shape(lambda: d_arch.make_params(
             config["draft"], acc, key, srv["dtype"])))
         tkv = put(jax.eval_shape(lambda: target.init_paged_cache(
             pages, ps, jnp.bfloat16)))

@@ -1,6 +1,6 @@
 """CPU rehearsal of one benchmark run at smoke size.
 
-    JAX_PLATFORMS=cpu python3 bench/checks/rehearse_cpu.py [--seconds 3] [--cell chat]
+    JAX_PLATFORMS=cpu python3 bench/checks/rehearse_cpu.py [--seconds 3] [--tree] [--trace 1]
 
 Drives the whole harness (weights and acceptance construction, warm-up,
 gateway, load generator, window, metric readers, the check against the
@@ -24,14 +24,7 @@ sys.path.insert(0, str(BENCH.parent / "src"))
 sys.path.insert(0, str(BENCH))
 
 import harness  # noqa: E402
-
-
-def smoke_model(layers: int, d: int, heads: int, ff: int) -> dict:
-    return {"hidden_size": d, "intermediate_size": ff,
-            "num_attention_heads": heads, "num_hidden_layers": layers,
-            "num_key_value_heads": 2, "vocab_size": 512,
-            "rms_norm_eps": 1e-06, "rope_theta": 1000000.0,
-            "tie_word_embeddings": True, "attention_bias": True}
+from archs import qwen2  # noqa: E402
 
 
 def smoke_cell(name: str, tree: bool = False, mix: str = "chat"):
@@ -43,8 +36,8 @@ def smoke_cell(name: str, tree: bool = False, mix: str = "chat"):
                       .read_text())
     config = copy.deepcopy(base)
     config["name"] = name
-    config["target"] = smoke_model(2, 128, 4, 256)
-    config["draft"] = smoke_model(1, 64, 4, 128)
+    config["target"] = qwen2.smoke(2, 128, 4, 256)
+    config["draft"] = qwen2.smoke(1, 64, 4, 128)
     config["serving"].update(max_batch=4, max_len=128, L_max=4,
                              dtype="float32")
     config["acceptance"].update(code_dims=16)
@@ -68,8 +61,11 @@ def main():
     ap.add_argument("--tree", action="store_true")
     ap.add_argument("--trace", default="0")
     a = ap.parse_args()
-    cell = smoke_cell("smoke", tree=a.tree)
-    harness.run(["--workload", "smoke", "--seed", a.seed, "--seconds",
+    # named after the cell it copies, so that the metrics the benchmark
+    # reads in that cell alone are read here too
+    name = "q15b-tree.chat" if a.tree else "q3b.chat"
+    cell = smoke_cell(name, tree=a.tree)
+    harness.run(["--workload", name, "--seed", a.seed, "--seconds",
                  a.seconds, "--trace", a.trace], allow_cpu=True, cell=cell)
 
 

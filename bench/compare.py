@@ -36,6 +36,21 @@ decisions from its own logits with the same random numbers.
 
 Every decision of the program lies on the served sequence: the prefix a
 decision conditions on is the prompt plus the tokens committed before it.
+
+The reference is ``references/<reference>.py``, named by the configuration
+(one for both models; a pair of two architectures gives one that tells them
+apart by each section's ``"arch"``).  The check calls two functions of it:
+
+    hidden_states(params, section, tokens, precision)
+        final hidden states (N, T, d) of token rows (N, T), float32;
+    sequence_log_probs(params, x_row, precision)
+        (T, V) log-softmax of the next token at every position of one row
+        from its final hidden states (T, d).
+
+``precision`` is ``"f32"`` for the reference and ``"fp8"`` for the control.
+The weights are the architecture's own seeded ones (``arch.of(section)
+.make_params`` with the run's keys), regenerated here after the program's
+are freed.
 """
 
 from __future__ import annotations
@@ -48,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import weights
 
 THRESH = 1e-3           # a token counts in the draft check above this mass
@@ -315,8 +331,8 @@ def check(config: dict, seed: int, samples: list, rounds, T_max: int,
     acc = config["acceptance"]
     hidden = {}
     for role, key in (("target", kt), ("draft", kd)):
-        params = weights.make_params(config[role], acc, key,
-                                     config["serving"]["dtype"])
+        params = arch.of(config[role]).make_params(
+            config[role], acc, key, config["serving"]["dtype"])
         for prec in ("f32",) + tuple(p for p in precisions if p != "program"):
             hidden[role, prec] = (ref.hidden_states(params, config[role],
                                                       tokens, prec), params)

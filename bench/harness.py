@@ -26,6 +26,7 @@ import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+SPAN_CAPACITY = 1 << 18     # the program's spans kept in a traced run
 
 
 class NoChip(SystemExit):
@@ -92,7 +93,10 @@ def task_ranges(config: dict) -> list:
 
 
 class Run:
-    """What metric readers read: the cell, its window and its records."""
+    """What metric readers read: the cell, its window and its records; in a
+    traced run also the device trace's summary (``trace``), the program's
+    spans (``spans``) and each of its counters' change over the window
+    (``counters``)."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -122,6 +126,15 @@ class Run:
         t1 = self.t_close if t1 is None else t1
         return [p for p in self.recorder.prefills if t0 <= p[0] < t1]
 
+    def spans(self, name: str, t0=None, t1=None) -> list:
+        """(start, end) in seconds, on the clock the window is timed by, of
+        the program's finished spans called ``name`` that start in the
+        window (or in [t0, t1)); empty in an untraced run."""
+        t0 = self.t_open if t0 is None else t0
+        t1 = self.t_close if t1 is None else t1
+        return [(a, b) for n, a, b in self.span_log
+                if n == name and t0 <= a < t1]
+
 
 def percentile(values, q: float) -> float:
     vals = np.asarray(values, np.float64)
@@ -129,8 +142,9 @@ def percentile(values, q: float) -> float:
 
 
 async def drive(served, traffic, table, seconds: float, trace_dir,
-                seed: int, log):
-    """Ramp up, measure, and close; returns the window's facts."""
+                seed: int, log, tracer=None):
+    """Ramp up, measure, and close; returns the window's facts, with the
+    change of each of ``tracer``'s counters over the window."""
     import jax
 
     from loadgen import LoadGen
@@ -151,6 +165,7 @@ async def drive(served, traffic, table, seconds: float, trace_dir,
             raise RuntimeError("ramp-up did not fill the batch in 600 s")
         await asyncio.sleep(0.005)
     t_open = time.perf_counter()
+    counters_open = tracer.counters() if tracer is not None else {}
     trace = None
     if trace_dir is not None:
         # a steady part of the window: its middle, at most 4 s
@@ -169,6 +184,10 @@ async def drive(served, traffic, table, seconds: float, trace_dir,
     else:
         await asyncio.sleep(seconds)
     t_close = time.perf_counter()
+    counters = {}
+    if tracer is not None:
+        counters = {k: v - counters_open.get(k, 0)
+                    for k, v in tracer.counters().items()}
     lg.stop_sending()
     # latencies of requests sent in the window: wait (a minute at most)
     # for each one's first token
@@ -185,7 +204,8 @@ async def drive(served, traffic, table, seconds: float, trace_dir,
     if gw.round_error is not None:
         log(f"round error: {gw.round_error!r}")
     return dict(t_open=t_open, t_close=t_close, trace=trace,
-                outcomes=lg.outcomes, round_error=gw.round_error)
+                outcomes=lg.outcomes, round_error=gw.round_error,
+                counters=counters)
 
 
 def judge(readings: dict, limits: dict) -> bool:
@@ -258,11 +278,25 @@ def run(argv=None, *, allow_cpu: bool = False, cell=None,
     log(f"set-up: warm-up {time.perf_counter() - t0:.3f} s, "
         f"{len(compiles_since(t0))} programs compiled or loaded")
     recorder = Recorder(served, annotate=bool(args.trace))
-    trace_dir = None
+    trace_dir = tracer = None
     if args.trace:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
-    facts = asyncio.run(drive(served, traffic, table, args.seconds,
-                              trace_dir, args.seed, log))
+        # the program's own spans and counters, for the per-layer readers;
+        # on the host clock only (no profiler annotations)
+        from repro.obs import trace as obs_trace
+        tracer = obs_trace.install(obs_trace.Tracer(capacity=SPAN_CAPACITY))
+    try:
+        facts = asyncio.run(drive(served, traffic, table, args.seconds,
+                                  trace_dir, args.seed, log, tracer))
+    finally:
+        if tracer is not None:
+            obs_trace.uninstall()
+    span_log = []
+    if tracer is not None:
+        span_log = [(sp.name, sp.t0_ns * 1e-9, (sp.t0_ns + sp.dur_ns) * 1e-9)
+                    for sp in tracer.snapshot()]
+        log(f"program spans kept {len(span_log)}, dropped {tracer.dropped}; "
+            f"counters over the window {json.dumps(facts['counters'])}")
     t_open, t_close = facts["t_open"], facts["t_close"]
     compiles = [c for c in _COMPILES if t_open <= c[0] < t_close]
     stats = dev.memory_stats() or {}
@@ -294,6 +328,7 @@ def run(argv=None, *, allow_cpu: bool = False, cell=None,
               recorder=recorder, outcomes=outcomes,
               t_start=t_start, t_open=t_open, t_close=t_close,
               trace_window=facts["trace"], trace=summary,
+              span_log=span_log, counters=facts["counters"],
               device_kind=dev.device_kind, n_devices=len(devices))
     metrics = {}
     for m in metric_entries(spec, wl["name"], bool(args.trace)):

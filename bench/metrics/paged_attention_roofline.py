@@ -2,8 +2,10 @@
 traced window: the least time its calls need (attention FLOPs and the K/V
 bytes of the context tokens each call reads, from the shapes and context
 lengths the window ran: draft passes, J=1 target windows, prefills) over
-the kernel's device time, in percent."""
+the kernel's device time, in percent.  A model's attention is counted only
+where its architecture does it with this kernel (``ATTENTION_KERNEL``)."""
 
+import arch
 import roofline
 
 LAYER = "kernels"
@@ -14,18 +16,23 @@ KERNEL = r"paged_attention"
 
 def _work(run):
     t0, t1 = run.trace_window
+    mine = {role: arch.of(run.config[role]).ATTENTION_KERNEL
+            == "paged_attention" for role in ("target", "draft")}
     flops = nbytes = 0.0
     for r in run.window_rounds(t0, t1):
         w = roofline.round_work(run.config, r)
-        flops += w["draft_attn"][0]
-        nbytes += w["draft_attn"][1]
-        if r.J == 1:
+        if mine["draft"]:
+            flops += w["draft_attn"][0]
+            nbytes += w["draft_attn"][1]
+        if r.J == 1 and mine["target"]:
             flops += w["target_attn"][0]
             nbytes += w["target_attn"][1]
     for p in run.window_prefills(t0, t1):
         w = roofline.prefill_work(run.config, p[3])
-        flops += w["attn_flops"]
-        nbytes += w["attn_bytes"]
+        for role in ("target", "draft"):
+            if mine[role]:
+                flops += w[f"{role}_attn"][0]
+                nbytes += w[f"{role}_attn"][1]
     return flops, nbytes
 
 

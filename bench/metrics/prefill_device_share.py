@@ -1,7 +1,8 @@
 """Device time of the prefill programs in the traced window, over the
-traced window, in percent.  Prefill runs op by op (``add_streams`` calls
-the models' ``prefill`` outside any jitted step), so its programs are every
-XLA program other than the compiled round steps."""
+traced window, in percent.  ``add_streams`` runs one compiled
+``prefill_step`` program per prompt bucket (``jit_prefill_step``), which
+this reads among every XLA program other than the compiled round steps
+(with the small page-table updates and padding of an admission)."""
 
 LAYER = "engine prefill"
 SOURCE = "device_trace"

@@ -5,10 +5,14 @@ lengths a window ran: live rows only, each row's planned draft length (not
 the dispatched window), true prompt lengths (not their power-of-two
 buckets), and context tokens (not the unused tails of cache pages).  A
 later change that stops computing padding therefore cannot lift a share
-past 100%.
+past 100%.  What a token or a row of attention costs is the model's
+architecture's to say (``arch.of(section).token_flops`` and
+``.attention``); the draft and the target each count through their own.
 """
 
 from __future__ import annotations
+
+import arch
 
 # Published peaks per chip, keyed by ``jax.Device.device_kind``.
 # Source: Google Cloud documentation, "TPU v5e" (system architecture):
@@ -25,34 +29,6 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def dims(m: dict) -> dict:
-    d, H = m["hidden_size"], m["num_attention_heads"]
-    return dict(d=d, H=H, KV=m["num_key_value_heads"],
-                Dh=m.get("head_dim", d // H), F=m["intermediate_size"],
-                V=m["vocab_size"], n=m["num_hidden_layers"])
-
-
-def token_flops(m: dict) -> float:
-    """Matrix FLOPs per token of one forward pass, attention excluded:
-    q/k/v/o projections, the SwiGLU MLP, and the logits."""
-    x = dims(m)
-    proj = x["d"] * x["Dh"] * (2 * x["H"] + 2 * x["KV"])
-    mlp = 3 * x["d"] * x["F"]
-    return 2.0 * (x["n"] * (proj + mlp) + x["d"] * x["V"])
-
-
-def attention(m: dict, ctx0: int, T: int, kv_bytes: int = 2):
-    """(flops, bytes) of causal attention for one row: T queries at
-    positions ctx0 .. ctx0 + T - 1 over the cache, all layers.  Bytes read
-    K and V of the ctx0 + T context tokens once, and q in / out once."""
-    x = dims(m)
-    keys = T * ctx0 + T * (T + 1) // 2          # sum of context lengths
-    flops = 4.0 * x["n"] * x["H"] * x["Dh"] * keys
-    kv = 2 * x["KV"] * x["Dh"] * (ctx0 + T) * kv_bytes
-    qo = 2 * x["H"] * x["Dh"] * T * 2
-    return flops, float(x["n"] * (kv + qo))
-
-
 def tree_nodes(tokens, Lk: int) -> int:
     """Distinct draft prefixes of one row's (J, L) forest: the nodes of
     its prefix-deduplicated token tree."""
@@ -65,6 +41,7 @@ def round_work(config: dict, rnd) -> dict:
     the draft and target passes, and the attention FLOPs and bytes of the
     draft passes and of the target window apart."""
     t, dm = config["target"], config["draft"]
+    at, ad = arch.of(t), arch.of(dm)
     J = rnd.J
     out = dict(flops=0.0, draft_attn=[0.0, 0.0], target_attn=[0.0, 0.0],
                verify_positions=0)
@@ -72,14 +49,14 @@ def round_work(config: dict, rnd) -> dict:
         Lk = int(rnd.lengths[b])
         # draft: J runs of Lk + 1 single-token passes (the last writes d_L)
         for s in range(Lk + 1):
-            f, by = attention(dm, i0 + s, 1)
-            out["flops"] += J * (token_flops(dm) + f)
+            f, by = ad.attention(dm, i0 + s, 1)
+            out["flops"] += J * (ad.token_flops(dm) + f)
             out["draft_attn"][0] += J * f
             out["draft_attn"][1] += J * by
         # target: one window over pending and the row's draft tokens
         T = 1 + (Lk if J == 1 else tree_nodes(rnd.out["tokens"][b], Lk))
-        f, by = attention(t, i0, T)
-        out["flops"] += T * token_flops(t) + f
+        f, by = at.attention(t, i0, T)
+        out["flops"] += T * at.token_flops(t) + f
         out["target_attn"][0] += f
         out["target_attn"][1] += by
         out["verify_positions"] += Lk + 1
@@ -87,13 +64,15 @@ def round_work(config: dict, rnd) -> dict:
 
 
 def prefill_work(config: dict, M: int) -> dict:
-    """A prompt of M tokens: both models prefill its first M - 1."""
-    out = {"flops": 0.0, "attn_flops": 0.0, "attn_bytes": 0.0}
-    for m in (config["target"], config["draft"]):
-        f, by = attention(m, 0, M - 1)
-        out["flops"] += (M - 1) * token_flops(m) + f
-        out["attn_flops"] += f
-        out["attn_bytes"] += by
+    """A prompt of M tokens: both models prefill its first M - 1.  Model
+    FLOPs of both, and each model's attention FLOPs and bytes apart."""
+    out = {"flops": 0.0}
+    for role in ("target", "draft"):
+        m = config[role]
+        a = arch.of(m)
+        f, by = a.attention(m, 0, M - 1)
+        out["flops"] += (M - 1) * a.token_flops(m) + f
+        out[f"{role}_attn"] = [f, by]
     return out
 
 

@@ -2,9 +2,9 @@
 
 The benchmark's own copy of the steps of ``repro.launch.gateway.build_cell``
 for the engine backend: model configs from the configuration file, a paged
-``SpecEngine`` on the compiled round path, seeded weights made by
-``weights.make_params`` (with the acceptance construction), an
-``EngineBackend`` and a ``MultiSpinCell``.  Two departures, both so that
+``SpecEngine`` on the compiled round path, seeded weights (with the
+acceptance construction), both made by each model's architecture module
+(``arch.of``), an ``EngineBackend`` and a ``MultiSpinCell``.  Two departures, both so that
 set-up does only what the cell's traffic needs:
 
 * every request is admitted through ``add_streams`` with its own prompt:
@@ -15,8 +15,7 @@ set-up does only what the cell's traffic needs:
   (``(max_batch, L)`` for L in 1..L_max, at the configuration's draft width),
   the prompt buckets and prompt lengths the run's traffic draws, and the
   page-table updates of 1..max_batch rows that rounds and admissions make.
-  What then still loads from the compilation cache inside the window is
-  the program's own: each admission traces its prefill's layer scan anew.
+  The harness logs any program still compiled or loaded in the window.
 """
 
 from __future__ import annotations
@@ -25,23 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import weights
-
-
-def model_config(section: dict, name: str, dtype: str):
-    from repro.configs.base import ModelConfig
-
-    d, H = section["hidden_size"], section["num_attention_heads"]
-    return ModelConfig(
-        name=name, family="dense", num_layers=section["num_hidden_layers"],
-        d_model=d, num_heads=H, num_kv_heads=section["num_key_value_heads"],
-        head_dim=section.get("head_dim", d // H),
-        d_ff=section["intermediate_size"], vocab_size=section["vocab_size"],
-        activation="swiglu", norm="rmsnorm",
-        qkv_bias=bool(section["attention_bias"]),
-        rope_theta=float(section["rope_theta"]),
-        tie_embeddings=bool(section["tie_word_embeddings"]),
-        param_dtype=dtype, compute_dtype=dtype)
 
 
 class ServedCell:
@@ -55,10 +39,11 @@ class ServedCell:
         self.config = config
         srv = config["serving"]
         dtype = srv["dtype"]
-        self.tcfg = model_config(config["target"], config["name"] + ".target",
-                                 dtype)
-        self.dcfg = model_config(config["draft"], config["name"] + ".draft",
-                                 dtype)
+        t_arch, d_arch = arch.of(config["target"]), arch.of(config["draft"])
+        self.tcfg = t_arch.model_config(config["target"],
+                                        config["name"] + ".target", dtype)
+        self.dcfg = d_arch.model_config(config["draft"],
+                                        config["name"] + ".draft", dtype)
         self.max_batch = int(srv["max_batch"])
         self.max_len = int(srv["max_len"])
         self.L_max = int(srv["L_max"])
@@ -72,8 +57,8 @@ class ServedCell:
             compile_mode=srv["compile"], cache_dtype=weights.DTYPES[dtype])
         kt, kd = weights.pair_keys(seed)
         acc = config["acceptance"]
-        engine.t_params = weights.make_params(config["target"], acc, kt, dtype)
-        engine.d_params = weights.make_params(config["draft"], acc, kd, dtype)
+        engine.t_params = t_arch.make_params(config["target"], acc, kt, dtype)
+        engine.d_params = d_arch.make_params(config["draft"], acc, kd, dtype)
         for got, model in ((engine.t_params, engine.target),
                            (engine.d_params, engine.draft)):
             want = jax.eval_shape(model.init, jax.random.PRNGKey(0))

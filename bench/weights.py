@@ -11,8 +11,10 @@ shape, dtype or amount of work; only values change.
                cluster k belongs to task k * n_tasks // n_clusters, so each
                task owns one contiguous range of the vocabulary.
     code       the last ``code_dims`` + 1 residual dims form a bypass that
-               no layer reads or writes (those rows of wq/wk/wv/w_gate/w_up
-               and columns of wo/w_down are zero).  Embedding row y holds
+               no layer reads or writes (``live_dims``: the architecture
+               zeroes those rows of every weight that reads the residual
+               stream and those columns of every one that writes it, such
+               as wq/wk/wv/w_gate/w_up and wo/w_down).  Embedding row y holds
                ``code_norm * w_k(y)`` there (w_k a seeded unit vector per
                cluster) plus the constant ``code_norm`` in the last dim.
     ln_f       scale 1 + N(0, 0.1^2) on the other dims, ``code_gain`` on the
@@ -29,30 +31,33 @@ shape, dtype or amount of work; only values change.
                overlap by the ``task_alpha`` the config states; the
                ``task_sigma`` values are solved for that overlap.
     layers     every weight is seeded, so that the check sees each term
-               of a block move the logits: the projections N(0, 1/fan_in),
-               the QKV biases N(0, 0.5^2) and the RMSNorm scales
-               1 + N(0, 0.1^2) (on the dims the layers use).
+               of a block move the logits: for Qwen2 the projections
+               N(0, 1/fan_in), the QKV biases N(0, 0.5^2) and the RMSNorm
+               scales 1 + N(0, 0.1^2) (on the dims the layers use).
 
-Everything is made on the device in one jitted call per model, in the
-served dtype, layer by layer (``lax.map``) so no float32 copy of a whole
-stack is ever live.  The reference regenerates the same values by calling
-the same function with the same key.
+This module holds what no architecture changes: the cluster/task map, the
+code-carrying token table (``code_table``, also for an untied output head,
+which then carries the embedding's codes), the norm scales and the final
+norm's code gain.  Each architecture module (``bench/archs/<arch>.py``)
+builds its own layers around them and makes everything on the device in
+one jitted call per model, in the served dtype, layer by layer
+(``lax.map``) so no float32 copy of a whole stack is ever live.  The
+reference regenerates the same values by calling the same function with
+the same key.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-BIAS_SD = 0.5       # QKV biases: large enough to move every logit
 NORM_SD = 0.1       # RMSNorm scales: 1 + N(0, NORM_SD^2) on the live dims
 
 
-def _normal(key, shape, scale, dtype):
+def normal(key, shape, scale, dtype):
+    """N(0, scale^2) of ``shape``, drawn in float32, in ``dtype``."""
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
@@ -61,16 +66,30 @@ def cluster_task(n_clusters: int, n_tasks: int) -> np.ndarray:
     return (np.arange(n_clusters) * n_tasks) // n_clusters
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "acc", "dtype"))
-def _make(key, *, shape: tuple, acc: tuple, dtype: str):
-    (V, d, n_layers, H, KV, Dh, F, qkv_bias) = shape
-    (m, r, a, gain, sigmas) = acc
-    dt = DTYPES[dtype]
-    n_code = r + 1
-    live = jnp.arange(d) < d - n_code            # dims the layers use
-    k_embed, k_code, k_blocks = jax.random.split(key, 3)
+def live_dims(d: int, acc: tuple):
+    """(d,) mask of the residual dims the layers use: all but the code's
+    ``code_dims`` + 1 bypass dims at the end."""
+    return jnp.arange(d) < d - (acc[1] + 1)
 
-    # embedding, in row chunks so no float32 (V, d) copy is live
+
+def norm_scale(key, live, dtype):
+    """An RMSNorm scale: 1 + N(0, NORM_SD^2) on the live dims, 1 on the
+    code dims."""
+    d = live.shape[0]
+    return jnp.where(live, 1.0 + normal(key, (d,), NORM_SD, jnp.float32),
+                     1.0).astype(dtype)
+
+
+def code_table(k_code, k_rows, V: int, d: int, acc: tuple, dtype):
+    """A (V, d) table of token rows that carries the cluster codes: the
+    random spread from ``k_rows`` on the live dims, ``code_norm * w_k(y)``
+    and the constant ``code_norm`` on the code dims, with the cluster
+    codes w_k drawn from ``k_code``.  Two tables from one ``k_code`` (an
+    embedding and an untied output head) carry the same codes.  Built in
+    row chunks, so no float32 (V, d) copy is live; call it inside a jitted
+    function."""
+    (m, r, a, _, sigmas) = acc
+    n_code = r + 1
     n_clusters = V // m
     task_of_cluster = jnp.asarray(cluster_task(n_clusters, len(sigmas)))
     sig = jnp.asarray(sigmas, jnp.float32)[task_of_cluster]      # (C,)
@@ -79,85 +98,35 @@ def _make(key, *, shape: tuple, acc: tuple, dtype: str):
     n_chunks = 16 if V % (16 * m) == 0 else 1
     rows = V // n_chunks
 
-    def embed_chunk(i):
+    def chunk(i):
         y = i * rows + jnp.arange(rows)
         c = y // m
-        rnd = jax.random.normal(jax.random.fold_in(k_embed, i),
+        rnd = jax.random.normal(jax.random.fold_in(k_rows, i),
                                 (rows, d - n_code), jnp.float32)
         rnd = rnd * (sig[c] / np.sqrt(d))[:, None]
         code = a * codes[c]
         const = jnp.full((rows, 1), a, jnp.float32)
-        return jnp.concatenate([rnd, code, const], axis=1).astype(dt)
+        return jnp.concatenate([rnd, code, const], axis=1).astype(dtype)
 
-    embed = jax.lax.map(embed_chunk, jnp.arange(n_chunks)).reshape(V, d)
+    return jax.lax.map(chunk, jnp.arange(n_chunks)).reshape(V, d)
 
-    def scale(k):
-        return jnp.where(live, 1.0 + _normal(k, (d,), NORM_SD, jnp.float32),
-                         1.0).astype(dt)
 
-    def block(k):
-        ks = jax.random.split(k, 12)
-        rows_in = live[:, None]                   # zero the code dims' rows
-        cols_out = live[None, :]                  # ... and output columns
-        p = {
-            "ln_attn": {"scale": scale(ks[7])},
-            "attn": {
-                "wq": jnp.where(rows_in, _normal(ks[0], (d, H * Dh),
-                                                 d ** -0.5, dt), 0),
-                "wk": jnp.where(rows_in, _normal(ks[1], (d, KV * Dh),
-                                                 d ** -0.5, dt), 0),
-                "wv": jnp.where(rows_in, _normal(ks[2], (d, KV * Dh),
-                                                 d ** -0.5, dt), 0),
-                "wo": jnp.where(cols_out, _normal(ks[3], (H * Dh, d),
-                                                  (H * Dh) ** -0.5, dt), 0),
-            },
-            "ln_mlp": {"scale": scale(ks[8])},
-            "mlp": {
-                "w_gate": jnp.where(rows_in, _normal(ks[4], (d, F),
-                                                     d ** -0.5, dt), 0),
-                "w_up": jnp.where(rows_in, _normal(ks[5], (d, F),
-                                                   d ** -0.5, dt), 0),
-                "w_down": jnp.where(cols_out, _normal(ks[6], (F, d),
-                                                      F ** -0.5, dt), 0),
-            },
-        }
-        if qkv_bias:
-            p["attn"]["bq"] = _normal(ks[9], (H * Dh,), BIAS_SD, dt)
-            p["attn"]["bk"] = _normal(ks[10], (KV * Dh,), BIAS_SD, dt)
-            p["attn"]["bv"] = _normal(ks[11], (KV * Dh,), BIAS_SD, dt)
-        return p
-
-    k_blocks, k_lnf = jax.random.split(k_blocks)
-    blocks = jax.lax.map(block, jax.random.split(k_blocks, n_layers))
-    ln_f = jnp.concatenate([1.0 + _normal(k_lnf, (d - n_code,), NORM_SD,
+def final_scale(key, d: int, acc: tuple, dtype):
+    """The final norm's scale: 1 + N(0, NORM_SD^2) on the live dims,
+    ``code_gain`` on the code dims and ``-code_gain`` on the constant dim."""
+    r, gain = acc[1], acc[3]
+    return jnp.concatenate([1.0 + normal(key, (d - r - 1,), NORM_SD,
                                           jnp.float32),
                             jnp.full((r,), gain, jnp.float32),
-                            jnp.full((1,), -gain, jnp.float32)]).astype(dt)
-    return {"embed": embed, "ln_f": {"scale": ln_f}, "blocks": blocks}
-
-
-def model_shape(m: dict) -> tuple:
-    """The static shape tuple of one model section of a config file."""
-    d, H = m["hidden_size"], m["num_attention_heads"]
-    return (m["vocab_size"], d, m["num_hidden_layers"], H,
-            m["num_key_value_heads"], m.get("head_dim", d // H),
-            m["intermediate_size"], bool(m["attention_bias"]))
+                            jnp.full((1,), -gain, jnp.float32)]).astype(dtype)
 
 
 def acceptance_params(acc: dict) -> tuple:
+    """The acceptance section as the static tuple the helpers above take:
+    (cluster_size, code_dims, code_norm, code_gain, task_sigma)."""
     return (int(acc["cluster_size"]), int(acc["code_dims"]),
             float(acc["code_norm"]), float(acc["code_gain"]),
             tuple(float(s) for s in acc["task_sigma"]))
-
-
-def make_params(model: dict, acc: dict, key, dtype: str = "bfloat16"):
-    """Weights of one model (a ``target``/``draft`` section of a config
-    file) from ``key``, in the tree layout of a tied-embedding dense
-    decoder: embed, ln_f, and layer-stacked blocks."""
-    if not model["tie_word_embeddings"]:
-        raise ValueError("the acceptance construction needs tied embeddings")
-    return _make(key, shape=model_shape(model), acc=acceptance_params(acc),
-                 dtype=dtype)
 
 
 def pair_keys(seed: int):
