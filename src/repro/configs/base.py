@@ -30,6 +30,13 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: Optional[float] = 10000.0
     tie_embeddings: bool = False
+    norm_eps: Optional[float] = None  # None: the norm's own default
+    # --- attention kind: "gqa" | "mla" (multi-head latent attention) ---
+    attention: str = "gqa"
+    kv_lora_rank: int = 0             # MLA: latent width of c_kv
+    qk_nope_head_dim: int = 0         # MLA: per-head q/k dims without rope
+    qk_rope_head_dim: int = 0         # MLA: per-head q/k dims with rope
+    v_head_dim: int = 0               # MLA: per-head value width
     # --- MoE ---
     num_experts: int = 0
     top_k: int = 0
@@ -39,6 +46,13 @@ class ModelConfig:
     dense_residual: bool = False
     first_k_dense: int = 0            # leading dense layers in a MoE stack
     capacity_factor: float = 1.25
+    # router "softmax" (capacity-dropped dispatch) or "sigmoid" (DeepSeek-V3
+    # ``noaux_tc``: bias-corrected choice, normalised gates times
+    # ``routed_scale``, dropless dispatch over the experts held here)
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    experts_held: int = 0             # experts held here of num_experts (0: all)
+    first_expert: int = 0             # index of the first expert held here
     # --- SSM (Mamba2) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -62,6 +76,21 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"unknown attention kind {self.attention!r}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+
+    @property
+    def num_experts_held(self) -> int:
+        """Routed experts whose weights this model holds (a chip's share
+        under expert parallelism; all of them by default)."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def latent_dim(self) -> int:
+        """MLA: width of one cached latent row (c_kv and the roped k)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def is_sub_quadratic(self) -> bool:
@@ -96,7 +125,12 @@ class ModelConfig:
         )
         if self.num_experts:
             kw.update(num_experts=4, top_k=min(self.top_k, 2), moe_d_ff=32,
-                      shared_d_ff=64 if self.num_shared_experts else 0)
+                      shared_d_ff=64 if self.num_shared_experts else 0,
+                      experts_held=0, first_expert=0)
+        if self.attention == "mla":
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                      qk_rope_head_dim=8, v_head_dim=16, head_dim=24,
+                      num_kv_heads=4)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8)
         if self.family == "hybrid":

@@ -37,12 +37,15 @@ class VerifyResult:
         at position n_k; positions > n_k are padding (0).
     output_len:    (B,) int32 — n_k + 1 (paper: N_k, includes the extra token).
     accept_mask:   (B, L) bool — per-position Bernoulli outcomes A_{k,l}.
+    routing:       (2,) int32 or None — the target pass's routing counts
+        (routed pairs, experts touched) for sigmoid-routed experts.
     """
 
     accept_counts: jax.Array
     output_tokens: jax.Array
     output_len: jax.Array
     accept_mask: jax.Array
+    routing: jax.Array | None = None
 
 
 # pytree registration: jitted round steps (serving/compiled.py) return a
@@ -50,7 +53,7 @@ class VerifyResult:
 jax.tree_util.register_dataclass(
     VerifyResult,
     data_fields=["accept_counts", "output_tokens", "output_len",
-                 "accept_mask"],
+                 "accept_mask", "routing"],
     meta_fields=[])
 
 
@@ -72,7 +75,7 @@ class TreeVerifyResult(VerifyResult):
 jax.tree_util.register_dataclass(
     TreeVerifyResult,
     data_fields=["accept_counts", "output_tokens", "output_len",
-                 "accept_mask", "winner", "node_valid"],
+                 "accept_mask", "routing", "winner", "node_valid"],
     meta_fields=[])
 
 

@@ -140,6 +140,27 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths):
     return out
 
 
+def latent_attention(q, pool, page_table, lengths, *, dv: int,
+                     scale: float):
+    """Absorbed multi-head latent attention through a paged latent pool.
+
+    q: (B, T, H, C) latent-space queries; pool: (P, ps, C) latent rows;
+    page_table: (B, n_slots) int32 (-1 = unmapped); lengths: (B,) valid
+    rows for window row 0 (row t attends [0, lengths + t)).  Values are
+    each row's first ``dv`` columns; returns (B, T, H, dv).
+    """
+    with _span("ops.latent_attention", q):
+        if _use_pallas():
+            from .latent_attention import latent_attention_pallas
+            out = latent_attention_pallas(q, pool, page_table, lengths,
+                                          dv=dv, scale=scale,
+                                          interpret=_interpret())
+        else:
+            out = ref.latent_attention_ref(q, pool, page_table, lengths,
+                                           dv=dv, scale=scale)
+    return out
+
+
 def tree_attention(q, k_cache, v_cache, lengths, win_mask):
     """Token-tree verification window over a contiguous KV cache.
 
@@ -186,6 +207,28 @@ def decode_attention_q8(q, k_cache, v_cache, k_scale, v_scale, lengths):
             out = ref.decode_attention_quantized_ref(q, k_cache, v_cache,
                                                      k_scale, v_scale,
                                                      lengths)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Mixture of experts
+# ---------------------------------------------------------------------------
+
+def expert_matmul(x, w_gate, w_up, w_down, group_sizes, *, tm: int):
+    """SwiGLU experts over rows sorted by expert (dropless dispatch).
+
+    x: (M, d); w_gate / w_up: (E, d, F); w_down: (E, F, d); group_sizes:
+    (E,) rows per expert in order, summing to at most M.  ``tm`` is the
+    kernel's row tile.  Returns (M, d) float32; rows past the last group
+    (pairs of experts held elsewhere) are zero.
+    """
+    with _span("ops.expert_matmul", x):
+        if _use_pallas():
+            from .expert_matmul import expert_matmul_pallas
+            out = expert_matmul_pallas(x, w_gate, w_up, w_down, group_sizes,
+                                       tm=tm, interpret=_interpret())
+        else:
+            out = ref.expert_matmul_ref(x, w_gate, w_up, w_down, group_sizes)
     return out
 
 

@@ -68,19 +68,27 @@ def update_softmax(q_ref, k_ref, v_ref, visible, m_scr, l_scr, acc_scr, *,
         q = q_ref[0, h].astype(jnp.float32)              # (R, D)
         k = k_ref[0, :, h, :].astype(jnp.float32)        # (w, D)
         v = v_ref[0, :, h, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(visible, s, _NEG)
-        m_prev = m_scr[h, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[h, :, :1] = (l_scr[h, :, :1] * corr
-                           + jnp.sum(p, axis=-1, keepdims=True))
-        m_scr[h, :, :1] = m_new
-        acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        softmax_step(h, q, k, v, visible, m_scr, l_scr, acc_scr, scale=scale)
+
+
+def softmax_step(h: int, q, k, v, visible, m_scr, l_scr, acc_scr, *,
+                 scale: float):
+    """One online-softmax step of state slot ``h``: queries q (R, D)
+    against keys k (w, D) and values v (w, Dv), already loaded as float32;
+    ``visible`` is the (R, w) mask."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(visible, s, _NEG)
+    m_prev = m_scr[h, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[h, :, :1] = (l_scr[h, :, :1] * corr
+                       + jnp.sum(p, axis=-1, keepdims=True))
+    m_scr[h, :, :1] = m_new
+    acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def finish_softmax(o_ref, l_scr, acc_scr):

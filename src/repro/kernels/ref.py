@@ -94,6 +94,32 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return out.reshape(B, T, H, D)
 
 
+def latent_attention_ref(q: jax.Array, pool: jax.Array, page_table: jax.Array,
+                         lengths: jax.Array, *, dv: int,
+                         scale: float) -> jax.Array:
+    """Window attention of absorbed MLA queries through a paged latent pool.
+
+    q: (B, T, H, C) queries in the latent space (the no-rope part projected
+    through the key up-projection, then the roped part); pool: (P, ps, C)
+    latent rows (normed c_kv, then the roped key); page_table / lengths as
+    in ``paged_attention_ref``.  Scores are q . row; values are each row's
+    first ``dv`` columns.  Returns (B, T, H, dv).
+    """
+    B, T, H, C = q.shape
+    ps = pool.shape[1]
+    n_slots = page_table.shape[1]
+    S = n_slots * ps
+    rows = pool[jnp.maximum(page_table, 0)].reshape(B, S, C)
+    logits = jnp.einsum("bthc,bsc->bhts", q, rows).astype(jnp.float32) * scale
+    kpos = jnp.arange(S)
+    valid = kpos[None, None, :] < (lengths[:, None]
+                                   + jnp.arange(T)[None, :])[:, :, None]
+    valid = valid & jnp.repeat(page_table >= 0, ps, axis=1)[:, None, :]
+    logits = jnp.where(valid[:, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(pool.dtype)
+    return jnp.einsum("bhts,bsc->bthc", probs, rows[..., :dv])
+
+
 def tree_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                        lengths: jax.Array, win_mask: jax.Array,
                        scale: float | None = None) -> jax.Array:
@@ -371,3 +397,22 @@ def ssd_decode_ref(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     new_state = decay[..., None, None] * state + upd          # (b, h, p, n)
     y = jnp.einsum("bhpn,bhn->bhp", new_state, Ch)
     return y.astype(x.dtype), new_state
+
+
+# ---------------------------------------------------------------------------
+# Grouped expert matmul (dropless MoE)
+# ---------------------------------------------------------------------------
+
+def expert_matmul_ref(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                      w_down: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """SwiGLU experts over rows sorted by expert: rows
+    ``[start_e, start_e + group_sizes[e])`` of x (M, d) use expert e's
+    weights (E, d, F) / (E, F, d).  Returns (M, d) float32; rows past the
+    last group are zero."""
+    gate = jax.lax.ragged_dot(x, w_gate, group_sizes,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(x, w_up, group_sizes,
+                            preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jax.lax.ragged_dot(h, w_down, group_sizes,
+                              preferred_element_type=jnp.float32)

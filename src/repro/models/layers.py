@@ -504,3 +504,229 @@ def moe_apply(params: Params, x: jax.Array, mcfg: MoEConfig,
     if "dense" in params:
         y = y + mlp_apply(params["dense"], x, mcfg.activation)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3 MLA, no query compression)
+# ---------------------------------------------------------------------------
+
+def init_mla(key, d_model: int, num_heads: int, kv_lora_rank: int,
+             qk_nope: int, qk_rope: int, v_dim: int,
+             dtype=jnp.float32) -> Params:
+    """``wq`` (d, H * (nope + rope)); ``wkv_a`` (d, rank + rope), the latent
+    and the shared rope key; ``kv_norm`` on the latent; ``wkv_b``
+    (rank, H * (nope + v)), per head the key's no-rope part then the value;
+    ``wo`` (H * v, d)."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(kq, d_model, num_heads * (qk_nope + qk_rope), dtype),
+        "wkv_a": dense_init(ka, d_model, kv_lora_rank + qk_rope, dtype),
+        "kv_norm": init_rmsnorm(kv_lora_rank, dtype),
+        "wkv_b": dense_init(kb, kv_lora_rank,
+                            num_heads * (qk_nope + v_dim), dtype),
+        "wo": dense_init(ko, num_heads * v_dim, d_model, dtype),
+    }
+
+
+def rope_deinterleaved(x: jax.Array, positions: jax.Array,
+                       theta: float) -> jax.Array:
+    """RoPE as DeepSeek-V3 applies it: the rope dims are de-interleaved
+    (even dims first, then odd) and then rotated half against half."""
+    D = x.shape[-1]
+    perm = np.concatenate([np.arange(0, D, 2), np.arange(1, D, 2)])
+    return apply_rope(x[..., perm], positions, theta)
+
+
+def mla_project(params: Params, x: jax.Array, *, num_heads: int,
+                qk_nope: int, positions: jax.Array, rope_theta: float):
+    """Queries and latent rows of x (B, S, d): q_nope (B, S, H, nope), the
+    roped q_rope (B, S, H, rope), and the cached latent rows (B, S, rank +
+    rope): the normed latent, then the roped key shared by every head.
+    The latent's norm takes eps 1e-6, the published modeling code's default
+    for ``kv_a_layernorm`` (the blocks' norms take the config's)."""
+    B, S, _ = x.shape
+    q = (x @ params["wq"]).reshape(B, S, num_heads, -1)
+    q_nope = q[..., :qk_nope]
+    q_rope = rope_deinterleaved(q[..., qk_nope:], positions, rope_theta)
+    kv = x @ params["wkv_a"]
+    rank = params["kv_norm"]["scale"].shape[0]
+    c_kv = rmsnorm(params["kv_norm"], kv[..., :rank], eps=1e-6)
+    k_rope = rope_deinterleaved(kv[..., None, rank:], positions,
+                                rope_theta)[:, :, 0]
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def _mla_up(params: Params, num_heads: int, qk_nope: int):
+    """The key (rank, H, nope) and value (rank, H, v) up-projections."""
+    rank = params["wkv_b"].shape[0]
+    w = params["wkv_b"].reshape(rank, num_heads, -1)
+    return w[..., :qk_nope], w[..., qk_nope:]
+
+
+def mla_expanded(params: Params, q_nope, q_rope, latent, mask, *,
+                 num_heads: int, qk_nope: int) -> jax.Array:
+    """Attention of S queries over the same S latent rows with K and V
+    expanded per head (prefill and full forward).  mask broadcastable to
+    (B, H, S, S); returns (B, S, H * v)."""
+    B, S = q_nope.shape[:2]
+    rank = params["wkv_b"].shape[0]
+    kv = (latent[..., :rank] @ params["wkv_b"]).reshape(B, S, num_heads, -1)
+    k_nope, v = kv[..., :qk_nope], kv[..., qk_nope:]
+    k_rope = jnp.broadcast_to(latent[:, :, None, rank:],
+                              (B, S, num_heads, latent.shape[-1] - rank))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, k_rope.astype(k_nope.dtype)], axis=-1)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
+    logits = jnp.where(mask, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhst,bthd->bshd", probs, v).reshape(B, S, -1)
+
+
+def mla_apply(params: Params, x: jax.Array, *, num_heads: int, qk_nope: int,
+              positions: jax.Array, mask: jax.Array | None,
+              rope_theta: float, latent_cache: jax.Array | None = None,
+              cache_offset: jax.Array | int | None = None,
+              page_table: jax.Array | None = None, prefill: bool = False,
+              causal_window: bool = False):
+    """Multi-head latent attention.  Returns (out, latent) where latent is
+    the updated cache leaf, or the fresh latent rows without a cache.
+
+    Without a cache, and for a ``prefill`` (which starts from an empty
+    cache: the prompt attends only to itself), attention runs in the
+    EXPANDED form: K and V per head from the latent rows.  Windows against
+    a cache run in the ABSORBED form: the no-rope query goes through the
+    key up-projection into the latent space, the scores are taken against
+    the cached latent rows, the values are their latent part, and the
+    value up-projection follows.  A paged cache (``page_table``) runs that
+    through ``ops.latent_attention`` (the Pallas kernel, or its oracle);
+    it takes sequential windows only (``causal_window``).  A contiguous
+    cache (B, S, rank + rope) uses ``mask`` (B, 1, 1, T, S)."""
+    from repro.kernels import ops as kops
+
+    B, S, _ = x.shape
+    q_nope, q_rope, latent = mla_project(
+        params, x, num_heads=num_heads, qk_nope=qk_nope, positions=positions,
+        rope_theta=rope_theta)
+    if latent_cache is None or prefill:
+        causal = (mask[:, 0] if latent_cache is None
+                  else jnp.tril(jnp.ones((S, S), bool)))
+        ctx = mla_expanded(params, q_nope, q_rope, latent, causal,
+                           num_heads=num_heads, qk_nope=qk_nope)
+        out = ctx @ params["wo"]
+        if latent_cache is None:
+            return out, latent
+    rank = params["wkv_b"].shape[0]
+    w_uk, w_uv = _mla_up(params, num_heads, qk_nope)
+    new = latent[:, :, None]
+    if page_table is not None:
+        pool = paged_update_kv_cache(latent_cache[:, :, None], new,
+                                     cache_offset, page_table)[:, :, 0]
+    else:
+        pool = update_kv_cache(latent_cache[:, :, None], new,
+                               cache_offset)[:, :, 0]
+    if prefill:
+        return out, pool
+    q = jnp.concatenate([jnp.einsum("bthn,rhn->bthr", q_nope, w_uk),
+                         q_rope], axis=-1)
+    scale = 1.0 / np.sqrt(q_nope.shape[-1] + q_rope.shape[-1])
+    if page_table is not None:
+        if not causal_window:
+            raise NotImplementedError(
+                "a paged latent cache takes sequential windows only: token-"
+                "tree windows need a paged tree latent-attention path")
+        lengths = jnp.broadcast_to(jnp.asarray(cache_offset), (B,)) + 1
+        ctx = kops.latent_attention(q, pool, page_table, lengths, dv=rank,
+                                    scale=scale)
+    else:
+        logits = jnp.einsum("bthc,bsc->bhts", q,
+                            pool).astype(jnp.float32) * scale
+        logits = jnp.where(mask[:, 0], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(pool.dtype)
+        ctx = jnp.einsum("bhts,bsr->bthr", probs, pool[..., :rank])
+    ctx = jnp.einsum("bthr,rhv->bthv", ctx.astype(x.dtype), w_uv)
+    return ctx.reshape(B, S, -1) @ params["wo"], pool
+
+
+# ---------------------------------------------------------------------------
+# Sigmoid-routed experts, dropless, over the experts held here
+# ---------------------------------------------------------------------------
+
+def init_routed_experts(key, d_model: int, *, num_experts: int, held: int,
+                        d_ff: int, shared_d_ff: int,
+                        dtype=jnp.float32) -> Params:
+    """Router over all ``num_experts`` (float32 weights and the float32
+    score-correction bias), the weights of the ``held`` experts only, and
+    the shared expert (one SwiGLU of width ``shared_d_ff``)."""
+    keys = jax.random.split(key, 5)
+    scale = 1.0 / np.sqrt(d_model)
+    p = {
+        "router": dense_init(keys[0], d_model, num_experts, jnp.float32),
+        "bias": jnp.zeros((num_experts,), jnp.float32),
+        "w_gate": (std_normal(keys[1], (held, d_model, d_ff))
+                   * scale).astype(dtype),
+        "w_up": (std_normal(keys[2], (held, d_model, d_ff))
+                 * scale).astype(dtype),
+        "w_down": (std_normal(keys[3], (held, d_ff, d_model))
+                   * (1.0 / np.sqrt(d_ff))).astype(dtype),
+    }
+    if shared_d_ff:
+        p["shared"] = init_mlp(keys[4], d_model, shared_d_ff, "swiglu", dtype)
+    return p
+
+
+def route_sigmoid(params: Params, x: jax.Array, top_k: int,
+                  routed_scale: float):
+    """DeepSeek-V3 ``noaux_tc`` routing with one expert group, in float32:
+    sigmoid scores over every expert, the top-k chosen on score plus the
+    correction bias, gates the chosen scores without the bias, normalised
+    to sum 1 and times ``routed_scale``.  x: (T, d).  Returns (expert
+    ids (T, k), gates (T, k) float32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + params["bias"], top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=1)
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return idx, gates * routed_scale
+
+
+def routed_experts_apply(params: Params, x: jax.Array, *, top_k: int,
+                         routed_scale: float, first_expert: int = 0):
+    """Dropless MoE over the experts held here, plus the shared expert.
+
+    Routes every token over all experts (``route_sigmoid``), keeps the
+    (token, expert) pairs whose expert is held here (experts
+    ``first_expert`` .. ``first_expert + held - 1``), sorts them by expert
+    and runs only those rows through ``ops.expert_matmul``.  The pairs of
+    experts held elsewhere contribute nothing here: the result is this
+    share's part of the routed sum, plus the shared expert, which every
+    share computes alike.  x: (B, S, d).  Returns (y, counts) with counts
+    int32 (2,): (token, held expert) pairs computed, and held experts that
+    got at least one token."""
+    from repro.kernels import ops as kops
+
+    B, S, d = x.shape
+    T = B * S
+    held = params["w_gate"].shape[0]
+    E = params["router"].shape[1]
+    xt = x.reshape(T, d)
+    idx, gates = route_sigmoid(params, xt, top_k, routed_scale)
+    local = idx.reshape(-1) - first_expert
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    token = order // top_k
+    # row tile: about twice an expert's expected rows, so that an expert's
+    # weights are read once per pass (16 rows for a 72-token verify window)
+    tm = int(np.clip(2 ** np.ceil(np.log2(2 * T * top_k / E)), 16, 128))
+    rows = kops.expert_matmul(xt[token], params["w_gate"], params["w_up"],
+                              params["w_down"], sizes, tm=tm)
+    # rows of pairs held elsewhere come back zero
+    rows = rows * gates.reshape(-1)[order][:, None]
+    y = jnp.zeros((T, d), jnp.float32).at[token].add(rows)
+    y = y.astype(x.dtype).reshape(B, S, d)
+    if "shared" in params:
+        y = y + mlp_apply(params["shared"], x, "swiglu")
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y, counts

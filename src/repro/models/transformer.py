@@ -14,6 +14,7 @@ the lowered HLO stays O(1) in depth (fast multi-pod compiles, clean remat).
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -30,11 +31,15 @@ from .layers import (
     dense_init,
     embed_init,
     init_attention,
+    init_mla,
     init_mlp,
     init_moe,
+    init_routed_experts,
     make_norm,
+    mla_apply,
     mlp_apply,
     moe_apply,
+    routed_experts_apply,
     tree_window_mask,
 )
 
@@ -64,6 +69,16 @@ class DecoderLM:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.init_norm, self.norm = make_norm(cfg.norm)
+        if cfg.norm_eps is not None:
+            self.norm = functools.partial(self.norm, eps=cfg.norm_eps)
+        # multi-head latent attention: one latent cache leaf per layer stack
+        self.mla = cfg.attention == "mla"
+        # sigmoid-routed dropless experts held here: the blocks' auxiliary
+        # output is the routing counts (pairs, experts touched), not a loss
+        self.routed = bool(cfg.num_experts) and cfg.router == "sigmoid"
+        if self.routed != (self.mla and bool(cfg.num_experts)):
+            raise NotImplementedError("sigmoid-routed experts come with MLA "
+                                      "blocks, and MLA blocks take no others")
         self.moe_cfg = None
         if cfg.num_experts:
             self.moe_cfg = MoEConfig(
@@ -89,14 +104,25 @@ class DecoderLM:
         cfg = self.cfg
         dtype = _dtype(cfg.param_dtype)
         k_attn, k_mlp = jax.random.split(key)
+        if self.mla:
+            attn = init_mla(k_attn, cfg.d_model, cfg.num_heads,
+                            cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                            cfg.qk_rope_head_dim, cfg.v_head_dim, dtype)
+        else:
+            attn = init_attention(k_attn, cfg.d_model, cfg.num_heads,
+                                  cfg.num_kv_heads, cfg.head_dim,
+                                  qkv_bias=cfg.qkv_bias, dtype=dtype)
         p = {
             "ln_attn": self.init_norm(cfg.d_model, dtype),
-            "attn": init_attention(k_attn, cfg.d_model, cfg.num_heads,
-                                   cfg.num_kv_heads, cfg.head_dim,
-                                   qkv_bias=cfg.qkv_bias, dtype=dtype),
+            "attn": attn,
             "ln_mlp": self.init_norm(cfg.d_model, dtype),
         }
-        if moe:
+        if moe and self.routed:
+            p["moe"] = init_routed_experts(
+                k_mlp, cfg.d_model, num_experts=cfg.num_experts,
+                held=cfg.num_experts_held, d_ff=cfg.moe_d_ff,
+                shared_d_ff=cfg.shared_d_ff, dtype=dtype)
+        elif moe:
             p["moe"] = init_moe(k_mlp, cfg.d_model, self.moe_cfg, dtype)
         else:
             p["mlp"] = init_mlp(k_mlp, cfg.d_model, cfg.d_ff, cfg.activation, dtype)
@@ -138,8 +164,22 @@ class DecoderLM:
     # Cache
     # ------------------------------------------------------------------
 
+    def _latent_cache(self, lead: tuple, dtype) -> Params:
+        """MLA cache leaves: one latent row per position, (layers,) + lead
+        + (rank + rope,), for the scanned and the leading dense stacks."""
+        cfg = self.cfg
+        n_dense = cfg.first_k_dense if cfg.num_experts else 0
+        shape = lead + (cfg.latent_dim,)
+        cache = {"latent": jnp.zeros((cfg.num_layers - n_dense,) + shape,
+                                     dtype)}
+        if n_dense:
+            cache["dense_latent"] = jnp.zeros((n_dense,) + shape, dtype)
+        return cache
+
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16) -> Params:
         cfg = self.cfg
+        if self.mla:
+            return self._latent_cache((batch, max_len), dtype)
         n_scan = cfg.num_layers - (cfg.first_k_dense if cfg.num_experts else 0)
         n_dense = cfg.first_k_dense if cfg.num_experts else 0
         shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
@@ -159,8 +199,11 @@ class DecoderLM:
         NO batch axis — streams own pages, not rows.  Pair it with a
         ``"pages"`` (B, n_slots) int32 page table (``serving.PagedKVCache``)
         to form a per-batch cache view accepted by ``prefill`` /
-        ``forward_window``."""
+        ``forward_window``.  An MLA model's pool holds one latent row per
+        position instead: (layers, P, ps, rank + rope)."""
         cfg = self.cfg
+        if self.mla:
+            return self._latent_cache((num_pages, page_size), dtype)
         n_scan = cfg.num_layers - (cfg.first_k_dense if cfg.num_experts else 0)
         n_dense = cfg.first_k_dense if cfg.num_experts else 0
         shape = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
@@ -171,7 +214,8 @@ class DecoderLM:
             cache["dense_v"] = jnp.zeros((n_dense,) + shape, dtype)
         return cache
 
-    CACHE_BATCH_AXES = {"k": 1, "v": 1, "dense_k": 1, "dense_v": 1}
+    CACHE_BATCH_AXES = {"k": 1, "v": 1, "dense_k": 1, "dense_v": 1,
+                        "latent": 1, "dense_latent": 1}
 
     strip_view = staticmethod(strip_view)
 
@@ -179,9 +223,10 @@ class DecoderLM:
     def _cache_kv_capacity(cache: Params) -> int:
         """Logical KV positions per row: S for contiguous (B, S, KV, D)
         leaves, n_slots * page_size for a paged view."""
+        leaf = cache["latent" if "latent" in cache else "k"]
         if "pages" in cache:
-            return cache["pages"].shape[1] * cache["k"].shape[2]
-        return cache["k"].shape[2]
+            return cache["pages"].shape[1] * leaf.shape[2]
+        return leaf.shape[2]
 
     def concat_caches(self, caches: list) -> Params:
         """Stack per-row caches (ragged prefill) into one batch."""
@@ -195,8 +240,14 @@ class DecoderLM:
 
     def _block_apply(self, p: Params, x, *, moe: bool, positions, mask,
                      kv_cache=None, offset=None, moe_capacity=None,
-                     page_table=None, window_mask=None, causal_window=False):
+                     page_table=None, window_mask=None, causal_window=False,
+                     prefill=False):
         cfg = self.cfg
+        if self.mla:
+            return self._mla_block_apply(
+                p, x, moe=moe, positions=positions, mask=mask,
+                kv_cache=kv_cache, offset=offset, page_table=page_table,
+                causal_window=causal_window, prefill=prefill)
         h = self.norm(p["ln_attn"], x)
         attn_out, kv = attention_apply(
             p["attn"], h, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
@@ -213,10 +264,49 @@ class DecoderLM:
             mlp_out, aux = mlp_apply(p["mlp"], h, cfg.activation), jnp.zeros((), jnp.float32)
         return x + mlp_out, kv, aux
 
+    def _mla_block_apply(self, p: Params, x, *, moe: bool, positions, mask,
+                         kv_cache, offset, page_table, causal_window,
+                         prefill):
+        """An MLA block; its cache is the 1-tuple ``(latent,)``.  The
+        auxiliary output is the routing counts of sigmoid-routed experts
+        (int32 (2,), zeros in a dense block), or a zero loss in a model
+        without experts.  Token-tree windows (not ``causal_window``) over a
+        paged latent cache are refused in ``mla_apply``."""
+        cfg = self.cfg
+        h = self.norm(p["ln_attn"], x)
+        with jax.named_scope("mla"):
+            attn_out, latent = mla_apply(
+                p["attn"], h, num_heads=cfg.num_heads,
+                qk_nope=cfg.qk_nope_head_dim, positions=positions, mask=mask,
+                rope_theta=cfg.rope_theta,
+                latent_cache=None if kv_cache is None else kv_cache[0],
+                cache_offset=offset, page_table=page_table, prefill=prefill,
+                causal_window=causal_window)
+        x = x + attn_out
+        h = self.norm(p["ln_mlp"], x)
+        if moe:
+            with jax.named_scope("moe"):
+                mlp_out, aux = routed_experts_apply(
+                    p["moe"], h, top_k=cfg.top_k,
+                    routed_scale=cfg.routed_scale,
+                    first_expert=cfg.first_expert)
+        else:
+            mlp_out, aux = mlp_apply(p["mlp"], h, cfg.activation), self._aux0()
+        return x + mlp_out, (latent,), aux
+
+    def _aux0(self):
+        """A block's auxiliary output when it has none to give."""
+        if self.routed:
+            return jnp.zeros((2,), jnp.int32)
+        return jnp.zeros((), jnp.float32)
+
     def _stack_forward(self, params, x, positions, mask, cache=None, offset=None,
                        moe_capacity=None, window_mask=None,
-                       causal_window=False):
-        """Run all blocks; returns (hidden, new_cache, aux_sum)."""
+                       causal_window=False, prefill=False):
+        """Run all blocks; returns (hidden, new_cache, aux_sum).  aux_sum
+        is the load-balancing loss, or for sigmoid-routed experts the
+        routing counts (routed pairs, experts touched) summed over
+        layers."""
         cfg = self.cfg
         use_cache = cache is not None
         page_table = cache.get("pages") if use_cache else None
@@ -231,36 +321,40 @@ class DecoderLM:
                                      moe_capacity=moe_capacity,
                                      page_table=page_table,
                                      window_mask=window_mask,
-                                     causal_window=causal_window)
+                                     causal_window=causal_window,
+                                     prefill=prefill)
 
         if cfg.remat:
             block_fn = jax.checkpoint(block_fn)
 
         new_cache = dict(cache) if use_cache else None
-        aux_total = jnp.zeros((), jnp.float32)
-        stacks = [("blocks", "k", "v", block_fn)]
+        aux_total = self._aux0()
+        leaves = ("latent",) if self.mla else ("k", "v")
+        stacks = [("blocks", leaves, block_fn)]
         if "dense_blocks" in params:
             # leading dense blocks (MoE stacks with first_k_dense > 0)
             def dense_fn(p, x, kv_in):
                 return self._block_apply(
                     p, x, moe=False, positions=positions, mask=mask,
                     kv_cache=kv_in, offset=offset, page_table=page_table,
-                    window_mask=window_mask, causal_window=causal_window)
-            stacks.insert(0, ("dense_blocks", "dense_k", "dense_v", dense_fn))
+                    window_mask=window_mask, causal_window=causal_window,
+                    prefill=prefill)
+            stacks.insert(0, ("dense_blocks",
+                              tuple("dense_" + k for k in leaves), dense_fn))
 
-        for blocks, k_leaf, v_leaf, fn in stacks:
+        for blocks, names, fn in stacks:
             if use_cache:
-                x, k_all, v_all, aux = _scan_with_cache(
-                    fn, x, params[blocks], cache[k_leaf], cache[v_leaf],
+                x, new_leaves, aux = _scan_with_cache(
+                    fn, x, params[blocks], tuple(cache[k] for k in names),
                     cfg.scan_unroll)
-                new_cache[k_leaf], new_cache[v_leaf] = k_all, v_all
+                new_cache.update(zip(names, new_leaves))
             else:
                 def body(x, p, fn=fn):
                     x, _, aux = fn(p, x, None)
                     return x, aux
                 x, aux = jax.lax.scan(body, x, params[blocks],
                                       unroll=cfg.scan_unroll)
-            aux_total += jnp.sum(aux)
+            aux_total += jnp.sum(aux, axis=0)
         return x, new_cache, aux_total
 
     def _embed(self, params, tokens, prefix_embeds=None):
@@ -288,6 +382,9 @@ class DecoderLM:
         mask = (kj <= qi)[None, None, None]
         x, _, aux = self._stack_forward(params, x, positions, mask,
                                         moe_capacity=moe_capacity)
+        if self.routed:
+            # bias-corrected routing (``noaux_tc``) has no auxiliary loss
+            aux = jnp.zeros((), jnp.float32)
         return self._logits(params, x), aux
 
     def prefill(self, params, tokens, cache, prefix_embeds=None,
@@ -297,7 +394,9 @@ class DecoderLM:
         MoE dispatch defaults to exact no-drop capacity: serving prefill
         batches are modest (K devices x prompt) and the cache must reflect
         the exact model for verification to stay exact.  Pass an explicit
-        capacity factor for throughput-oriented bulk prefill.
+        capacity factor for throughput-oriented bulk prefill.  The third
+        value is the blocks' auxiliary output (``_stack_forward``): for
+        sigmoid-routed experts, the pass's routing counts.
         """
         if moe_capacity == "no_drop":
             moe_capacity = self.no_drop_capacity if self.moe_cfg else None
@@ -314,11 +413,12 @@ class DecoderLM:
         x, cache, aux = self._stack_forward(params, x, positions, mask,
                                             cache=cache, offset=offset,
                                             moe_capacity=moe_capacity,
-                                            causal_window=True)
+                                            causal_window=True,
+                                            prefill=self.mla)
         return self._logits(params, x), cache, aux
 
     def forward_window(self, params, tokens, cache, pos, window_mask=None,
-                       window_depth=None):
+                       window_depth=None, return_aux=False):
         """T new tokens against an existing cache.
 
         tokens: (B, T); pos: (B,) per-row write offsets (current lengths).
@@ -340,6 +440,9 @@ class DecoderLM:
         num window tokens): speculative verification must score with the exact
         model distribution, and capacity dropping is batch-coupled.  Training
         and prefill keep the configured capacity factor (DESIGN.md §3).
+
+        ``return_aux`` adds the blocks' auxiliary output as a third value
+        (for sigmoid-routed experts: the pass's routing counts).
         """
         x = self._embed(params, tokens)
         B, T, _ = x.shape
@@ -358,37 +461,40 @@ class DecoderLM:
         # pos + t): a window_depth WITHOUT a window_mask would put rope
         # positions out of step with the kernels' row-index mask, so it is
         # excluded from kernel dispatch.
-        x, cache, _ = self._stack_forward(
+        x, cache, aux = self._stack_forward(
             params, x, positions, mask, cache=cache, offset=pos,
             moe_capacity=moe_capacity, window_mask=window_mask,
             causal_window=window_mask is None and window_depth is None)
+        if return_aux:
+            return self._logits(params, x), cache, aux
         return self._logits(params, x), cache
 
     def num_params(self, params) -> int:
         return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
 
 
-def _scan_with_cache(layer_fn, x, blocks, k_all, v_all, unroll):
-    """Scan ``layer_fn(p, x, (k, v)) -> (x, (k, v), aux)`` over stacked
-    layers with the stacked K/V caches (layers first) in the CARRY: each
-    layer reads its slice and writes it back in place.  Scanned over as
-    inputs instead, the caches would come back as a new stacked output, a
-    fresh copy of every pool per window pass."""
+def _scan_with_cache(layer_fn, x, blocks, leaves, unroll):
+    """Scan ``layer_fn(p, x, kv) -> (x, kv, aux)`` over stacked layers with
+    the stacked cache ``leaves`` (a tuple, layers first: (k, v), or the MLA
+    latent alone) in the CARRY: each layer reads its slices and writes them
+    back in place.  Scanned over as inputs instead, the caches would come
+    back as a new stacked output, a fresh copy of every pool per window
+    pass."""
 
     def body(carry, xs):
-        x, k_all, v_all = carry
+        x, *stacked = carry
         p, i = xs
-        kv_in = (jax.lax.dynamic_index_in_dim(k_all, i, keepdims=False),
-                 jax.lax.dynamic_index_in_dim(v_all, i, keepdims=False))
-        x, (k, v), aux = layer_fn(p, x, kv_in)
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, k, i, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, v, i, 0)
-        return (x, k_all, v_all), aux
+        kv_in = tuple(jax.lax.dynamic_index_in_dim(a, i, keepdims=False)
+                      for a in stacked)
+        x, kv, aux = layer_fn(p, x, kv_in)
+        stacked = [jax.lax.dynamic_update_index_in_dim(a, n, i, 0)
+                   for a, n in zip(stacked, kv)]
+        return (x, *stacked), aux
 
-    layers = jnp.arange(k_all.shape[0], dtype=jnp.int32)
-    (x, k_all, v_all), aux = jax.lax.scan(body, (x, k_all, v_all),
-                                          (blocks, layers), unroll=unroll)
-    return x, k_all, v_all, aux
+    layers = jnp.arange(leaves[0].shape[0], dtype=jnp.int32)
+    (x, *leaves), aux = jax.lax.scan(body, (x, *leaves), (blocks, layers),
+                                     unroll=unroll)
+    return x, tuple(leaves), aux
 
 
 def _stack(trees: list[Params]) -> Params:

@@ -58,8 +58,15 @@ def _ring(bytes_, ways):
 
 def attention_flops(T, Skv, cfg, causal_half=False):
     d, H, KV, Dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    proj = 2 * T * d * (H * Dh) * 2 + 2 * T * d * (KV * Dh) * 2   # q,o + k,v
     factor = 0.5 if causal_half else 1.0
+    if cfg.attention == "mla":
+        # q, latent + rope key, per-token k/v up-projection, o; then scores
+        # over (nope + rope) dims and values over v dims
+        r, v = cfg.kv_lora_rank, cfg.v_head_dim
+        proj = 2 * T * (d * H * Dh + d * cfg.latent_dim
+                        + r * H * (cfg.qk_nope_head_dim + v) + H * v * d)
+        return proj + 2 * T * Skv * H * (Dh + v) * factor
+    proj = 2 * T * d * (H * Dh) * 2 + 2 * T * d * (KV * Dh) * 2   # q,o + k,v
     scores = 2 * T * Skv * H * Dh * factor * 2                     # qk^T + av
     return proj + scores
 
@@ -211,8 +218,9 @@ def roofline_terms(cfg: ModelConfig, shape: InputShape, mesh: MeshInfo,
             tb.add("scores_hbm", hbm=sc * n_att_layers
                    * (3.0 if training else 1.0) * 2)
         if decode:
-            kvb = (B * Skv * cfg.num_kv_heads * cfg.head_dim * 2 * kv_bytes
-                   * n_att_layers / chips)
+            row = (cfg.latent_dim if cfg.attention == "mla"
+                   else cfg.num_kv_heads * cfg.head_dim * 2)
+            kvb = B * Skv * row * kv_bytes * n_att_layers / chips
             tb.add("kv_cache_hbm", hbm=kvb)
             if not flash and draft_window > 0:
                 # XLA decode materializes (B, H, T, Skv) f32 scores

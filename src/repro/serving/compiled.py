@@ -24,7 +24,8 @@ and the paged admission prefill:
                        prompt bucket; donates both KV caches (argnum 1)
                        and returns only the caches, so the final norm and
                        the vocabulary projection over every prompt
-                       position are never computed.  The eager prefill
+                       position are never computed (a target with
+                       sigmoid-routed experts adds its routing counts).  The eager prefill
                        remains for ``eager`` mode, the contiguous
                        ``start()`` and ``serving/engine.py``.
 
@@ -191,6 +192,7 @@ def build_round_steps(target_model, draft_model, *, mode: str,
         raise ValueError(f"compile_mode must be one of {COMPILE_MODES}, "
                          f"got {mode!r}")
     donate = mode == "jit+donate"
+    routed = getattr(target_model, "routed", False)
 
     def _record(kind: str, n: int, L: int):
         if record is not None:
@@ -208,10 +210,16 @@ def build_round_steps(target_model, draft_model, *, mode: str,
         _record("verify", tokens.shape[0], tokens.shape[1])
         cache = kv if pages is None else dict(kv, pages=pages)
         window = jnp.concatenate([pending[:, None], tokens], axis=1)
-        logits, cache = target_model.forward_window(params, window, cache,
-                                                    tpos)
+        if routed:
+            logits, cache, routing = target_model.forward_window(
+                params, window, cache, tpos, return_aux=True)
+        else:
+            logits, cache = target_model.forward_window(params, window,
+                                                        cache, tpos)
         res = verify_drafts(key, tokens, probs, logits, q_idx=q_idx,
                             q_val=q_val, draft_len=draft_len)
+        if routed:
+            res = dataclasses.replace(res, routing=routing)
         return res, strip_view(cache)
 
     def commit(pending, target_pos, draft_pos, rows, skip, output_tokens,
@@ -258,8 +266,13 @@ def build_round_steps(target_model, draft_model, *, mode: str,
         caches = []
         for model, p, c, pt in zip((target_model, draft_model), params, kv,
                                    pages):
-            _, cache, _ = model.prefill(p, tokens[:, :-1], dict(c, pages=pt))
+            _, cache, aux = model.prefill(p, tokens[:, :-1],
+                                          dict(c, pages=pt))
             caches.append(strip_view(cache))
+            if model is target_model and routed:
+                routing = aux
+        if routed:
+            return caches[0], caches[1], routing
         return tuple(caches)
 
     if mode == "eager":

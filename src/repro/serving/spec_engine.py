@@ -56,6 +56,9 @@ from .kv_cache import (
 )
 
 CACHE_KINDS = ("contiguous", "paged")
+_NO_MLA_TREE = ("token-tree verification (draft_width > 1) with an MLA "
+                "target needs a paged tree latent-attention path, which the "
+                "engine does not have")
 
 
 def _span(name: str, args: dict | None = None):
@@ -127,15 +130,21 @@ class SpecEngine:
     no extra forward pass (span ``engine.kv_commit``); ``"repair"`` keeps
     the reference re-forward over ``[pending, accepted path]`` (span
     ``engine.cache_repair``).  Both commit identical tokens at the same
-    seed (tested, and asserted by ``bench_beyond --engine``)."""
+    seed (tested, and asserted by ``bench_beyond --engine``).
+
+    ``draft_width``: the widest token tree the engine will be asked to
+    verify.  An MLA target refuses trees (> 1) here, and in every tree
+    round."""
 
     def __init__(self, target_cfg: ModelConfig, draft_cfg: ModelConfig,
                  max_len: int = 512, cache_dtype=jnp.float32,
                  cache_kind: str = "contiguous", page_size: int = 16,
                  num_pages: int | None = None, tree_commit: str = "scatter",
-                 compile_mode: str | None = None):
+                 compile_mode: str | None = None, draft_width: int = 1):
         assert target_cfg.vocab_size == draft_cfg.vocab_size, \
             "SLM/LLM pair must share a vocabulary"
+        if draft_width > 1 and target_cfg.attention == "mla":
+            raise NotImplementedError(_NO_MLA_TREE)
         if cache_kind not in CACHE_KINDS:
             raise ValueError(f"cache_kind must be one of {CACHE_KINDS}")
         if tree_commit not in ("scatter", "repair"):
@@ -160,6 +169,9 @@ class SpecEngine:
         self.draft_cfg = draft_cfg
         self.target = build_model(target_cfg)
         self.draft = build_model(draft_cfg)
+        # sigmoid-routed experts: the target passes' routing counts ride the
+        # fetches a round's commit and an admission already make
+        self.routed = getattr(self.target, "routed", False)
         self.max_len = max_len
         self.cache_dtype = cache_dtype
         self.cache_kind = cache_kind
@@ -222,6 +234,18 @@ class SpecEngine:
         self.host_syncs += 1
         trace.incr("engine.host_sync")
         return jax.device_get(value)
+
+    def _count_routing(self, counts) -> None:
+        """Add one target pass's routing counts (host values, summed over
+        its MoE layers) to the tracer's counters: ``engine.moe_routed_pairs``
+        (token, held expert) pairs computed, ``engine.moe_experts_touched``
+        held experts that got a token, ``engine.moe_layer_passes`` MoE layers
+        run."""
+        cfg = self.target_cfg
+        trace.incr("engine.moe_routed_pairs", int(counts[0]))
+        trace.incr("engine.moe_experts_touched", int(counts[1]))
+        trace.incr("engine.moe_layer_passes",
+                   cfg.num_layers - cfg.first_k_dense)
 
     # ------------------------------------------------------------------
 
@@ -369,23 +393,29 @@ class SpecEngine:
                 padded = jnp.concatenate([prompts, pad], axis=1)
             else:
                 padded = prompts
+        routing = None
         if self._steps.prefill is not None:
             # one compiled program per (n, Mb) bucket; in jit+donate the
             # pools are donated and the returned ones adopted
             with _span("engine.prefill"):
-                self.t_cache, self.d_cache = self._steps.prefill(
+                out = self._steps.prefill(
                     (self.t_params, self.d_params),
                     (self.t_cache, self.d_cache),
                     (self.t_pages.device_table(rows),
                      self.d_pages.device_table(rows)), padded)
+                self.t_cache, self.d_cache = out[:2]
+                if self.routed:
+                    routing = out[2]
         else:
             self._record_prefill(n, Mb)
             with _span("engine.prefill", {"model": "target"}):
                 t_view = dict(self.t_cache,
                               pages=self.t_pages.device_table(rows))
-                _, t_view, _ = self.target.prefill(self.t_params,
-                                                   padded[:, :-1], t_view)
+                _, t_view, aux = self.target.prefill(self.t_params,
+                                                     padded[:, :-1], t_view)
                 self.t_cache = strip_view(t_view)
+                if self.routed:
+                    routing = aux
             with _span("engine.prefill", {"model": "draft"}):
                 d_view = dict(self.d_cache,
                               pages=self.d_pages.device_table(rows))
@@ -402,8 +432,11 @@ class SpecEngine:
         # waits for the round still in flight and for these prefills
         with _span("engine.state_splice"):
             trace.incr("engine.admit_host_sync")
-            pending, tpos, dpos, pnp = jax.device_get(
-                (state.pending, state.target_pos, state.draft_pos, prompts))
+            pending, tpos, dpos, pnp, routing = jax.device_get(
+                (state.pending, state.target_pos, state.draft_pos, prompts,
+                 routing))
+            if routing is not None:
+                self._count_routing(routing)
             n_grow = max(0, max(r + 1 for r in rows) - B) if rows else 0
             pending = np.concatenate([np.asarray(pending),
                                       np.zeros(n_grow, pnp.dtype)])
@@ -658,6 +691,11 @@ class SpecEngine:
                 logits, t_cache, snaps = self.target.forward_window(
                     self.t_params, window, t_cache, ticket.target_pos,
                     return_snapshots=True)
+            elif self.routed:
+                logits, t_cache, routing = self.target.forward_window(
+                    self.t_params, window, t_cache, ticket.target_pos,
+                    return_aux=True)
+                snaps = None
             else:
                 logits, t_cache = self.target.forward_window(
                     self.t_params, window, t_cache, ticket.target_pos)
@@ -667,6 +705,8 @@ class SpecEngine:
             res = verify_drafts(key, draft_res.tokens, draft_res.probs,
                                 logits, q_idx=draft_res.q_idx,
                                 q_val=draft_res.q_val, draft_len=draft_len)
+            if self.routed:
+                res = dataclasses.replace(res, routing=routing)
 
         # target cache: row i processed [pending, d_1..d_n]; snapshot index
         # n (0-based: snapshot t is the state after feeding window[:, :t+1])
@@ -711,7 +751,11 @@ class SpecEngine:
             pend, tpos, dpos, emission = self._steps.commit(
                 state.pending, state.target_pos, state.draft_pos, rows_dev,
                 skip_dev, res.output_tokens, res.accept_counts)
-            pack = self._host_fetch(emission)    # the ONE host sync
+            if res.routing is None:
+                pack = self._host_fetch(emission)    # the ONE host sync
+            else:
+                pack, routing = self._host_fetch((emission, res.routing))
+                self._count_routing(routing)
             accepted = pack[:, 0].astype(np.int64)
             with _span("engine.page_free", {"B": n}):
                 for i, b in enumerate(row_list):
@@ -803,6 +847,8 @@ class SpecEngine:
         chain, the window IS the sequential window, and no fix-up runs:
         tokens and caches are bit-identical to ``spin_round``.
         """
+        if self.target_cfg.attention == "mla":
+            raise NotImplementedError(_NO_MLA_TREE)
         for role, cfg in (("target", self.target_cfg),
                           ("draft", self.draft_cfg)):
             # DecoderLM families only: the ancestor-masked window needs

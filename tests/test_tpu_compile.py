@@ -1,7 +1,8 @@
 """Compile rehearsals of the served path's Pallas kernels for a TPU v5e.
 
 Each case compiles one hot-path kernel at the real widths of the served
-pair (target ``qwen2.5-3b``, draft ``qwen3.5-0.8b``, bf16) for one chip of
+pair (target ``qwen2.5-3b``, draft ``qwen3.5-0.8b``, bf16), and of the
+Moonlight target's latent attention and expert matmul, for one chip of
 a described ``v5e:2x2`` topology, with the TPU compiler installed here and
 no chip attached.  The compiler refuses what interpret mode accepts (block
 shapes off the chip's tiling, scalars stored to vector memory, primitives
@@ -105,4 +106,37 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, arch, T):
         args = _attention_args(one_chip, arch, T,
                                tree=kernel == "paged_tree_attention")
     compiled = KERNELS[kernel].lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# Moonlight-16B-A3B (registry ``moonshot-v1-16b-a3b``): the paged latent
+# attention of J=1 windows, and the grouped expert matmul of a verify pass
+# (8 x 9 tokens, top 6: 432 rows, tile 16) and of a 256-token prefill
+# (1530 rows, tile 64) over the 16 experts one chip holds
+LATENT_CASES = [("latent_attention", T) for T in (1, 9)] + [
+    ("expert_matmul", M) for M in (432, 1530)]
+
+
+@pytest.mark.parametrize("kernel,n", LATENT_CASES,
+                         ids=[f"{k}-{n}" for k, n in LATENT_CASES])
+def test_moonlight_kernel_compiles_for_v5e(one_chip, kernel, n):
+    from repro.kernels.expert_matmul import expert_matmul_pallas
+    from repro.kernels.latent_attention import latent_attention_pallas
+
+    cfg = get_config("moonshot-v1-16b-a3b")
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    if kernel == "latent_attention":
+        fn = jax.jit(lambda *a: latent_attention_pallas(
+            *a, dv=cfg.kv_lora_rank, scale=cfg.head_dim ** -0.5))
+        args = [S((B, n, cfg.num_heads, cfg.latent_dim), bf),
+                S((N_PAGES, PAGE, cfg.latent_dim), bf),
+                S((B, N_SLOTS), jnp.int32), S((B,), jnp.int32)]
+    else:
+        d, F, E = cfg.d_model, cfg.moe_d_ff, 16
+        fn = jax.jit(lambda *a: expert_matmul_pallas(
+            *a, tm=16 if n < 1024 else 64))
+        args = [S((n, d), bf), S((E, d, F), bf), S((E, d, F), bf),
+                S((E, F, d), bf), S((E,), jnp.int32)]
+    compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
